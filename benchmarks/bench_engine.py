@@ -94,7 +94,7 @@ from repro.core.faults import FaultPlan, FaultSpec
 from repro.engine import (AdaptivePolicy, Arena, MatrixSig, MemoryGovernor,
                           SpgemmEngine, Telemetry, git_rev, total_traces,
                           utc_now_iso, validate_chrome_trace)
-from repro.kernels import spgemm_hash
+from repro.kernels import spgemm_hash, use_compile_cache
 from repro.serve import SpgemmService
 
 BENCH_JSON = Path(__file__).resolve().parent.parent / "BENCH_engine.json"
@@ -653,6 +653,7 @@ def main(argv=None):
                          "latency at <5%% over the tracing-disabled "
                          "baseline in BENCH_engine.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.requests < 1:
         ap.error("--requests must be >= 1")
     if args.smoke:

@@ -11,6 +11,7 @@ visible in the output.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict, List
 
 import jax
@@ -74,7 +75,9 @@ def generate(spec: MatrixSpec, *, scale: int | None = None,
         LARGE_SCALE if spec.large else DEFAULT_SCALE)
     n = max(spec.rows // s, 256)
     return random_csr(
-        jax.random.PRNGKey(hash(spec.name) % (2 ** 31) + seed), n, n,
+        # crc32, not hash(): str hashes are salted per process.
+        jax.random.PRNGKey(zlib.crc32(spec.name.encode()) % (2 ** 31) + seed),
+        n, n,
         avg_nnz_per_row=spec.avg_nnz,
         max_nnz_per_row=min(spec.max_nnz, n),
         distribution=spec.dist)

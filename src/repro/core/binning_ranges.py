@@ -6,12 +6,15 @@ The paper fixes per-kernel hash-table sizes (Tables 1–2) and then chooses
 ``sym 1.2x`` and ``num 2x`` best on average; we keep those as defaults and
 sweep the same grid in ``benchmarks/bench_binning_ranges.py``.
 
-TPU adaptation (DESIGN.md §5): the ladder geometry (×2 per rung) is kept,
-but the envelope is the ~16 MiB/core VMEM instead of the V100's 96 KB
-shared memory, so an extended ladder with much larger top rungs is also
-provided (``vmem_extended=True``).  Rows too large even for the top rung
-fall back to the ESC (HBM) accumulator — the analog of the paper's
-global-memory hash kernels (kernel8 symbolic / kernel7 numeric).
+TPU adaptation (DESIGN.md §5): the ladder geometry (×2 per rung) is kept.
+The compiled hash kernels keep their tables in the 1 MiB/core SMEM (the
+scalar probe loop cannot address VMEM), which holds the default ladders'
+top rungs with room to spare.  An extended ladder with much larger top
+rungs is also provided (``vmem_extended=True``); its rungs above 32768
+entries do not fit SMEM in the kernels that carry values, so they do not
+compile for the chip.  Rows too large even for the top rung fall back to
+the ESC (HBM) accumulator — the analog of the paper's global-memory hash
+kernels (kernel8 symbolic / kernel7 numeric).
 """
 from __future__ import annotations
 

@@ -1442,11 +1442,14 @@ class SpgemmEngine:
                 rec.spec, m=rec.spec.bounds[-1], n=rec.B.ncols)
             rec.entry.executable = merge
         parts = tuple(r.C for r in shard_results)
-        with tel.span("shard_merge", uid=rec.uid, n_shards=spec.n_shards):
+        with tel.span("shard_merge", uid=rec.uid,
+                      n_shards=spec.n_shards) as merge_span:
             if self.mesh is not None:
                 # Mesh placement commits each shard's result to its shard
                 # device; one jitted computation can't mix committed
                 # devices, so gather the parts home first.
+                merge_span.set(devices=tuple(
+                    next(iter(C.val.devices())).id for C in parts))
                 home = next(iter(parts[0].val.devices()))
                 parts = tuple(C if C.val.devices() == {home}
                               else jax.device_put(C, home) for C in parts)

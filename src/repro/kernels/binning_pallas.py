@@ -20,21 +20,24 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
 
-def _make_kernel(upper: Tuple[int, ...], num_bins: int, block: int,
+_LANES = 128
+
+
+def _make_kernel(upper: Tuple[int, ...], num_bins: int, rows: int,
                  m: int):
     def kernel(sizes_ref, hist_ref, max_ref):
         i = pl.program_id(0)
-        vals = sizes_ref[...]                          # (block,)
-        idx = i * block + jax.lax.iota(jnp.int32, block)
-        valid = idx < m
+        vals = sizes_ref[...]                          # (rows, 128)
+        r = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+        valid = (i * rows + r) * _LANES + lane < m
         # classify: first rung admitting the size == count of exceeded
         # bounds (vectorized Alg-1 range scan; bounds are static ints)
-        bin_ids = jnp.zeros((block,), jnp.int32)
+        bin_ids = jnp.zeros(vals.shape, jnp.int32)
         for bound in upper:
             bin_ids += (vals > bound).astype(jnp.int32)
 
@@ -43,14 +46,17 @@ def _make_kernel(upper: Tuple[int, ...], num_bins: int, block: int,
             hist_ref[...] = jnp.zeros_like(hist_ref)
             max_ref[...] = jnp.zeros_like(max_ref)
 
-        # local histogram (VMEM) -> one accumulate into the output line
-        local = jnp.zeros((num_bins,), jnp.int32)
+        # local histogram (one vector line) -> one accumulate into the
+        # output line
+        out_lane = jax.lax.broadcasted_iota(jnp.int32, hist_ref.shape, 1)
+        local = jnp.zeros(hist_ref.shape, jnp.int32)
         for b in range(num_bins):
-            local = local.at[b].set(
-                jnp.sum(((bin_ids == b) & valid).astype(jnp.int32)))
-        hist_ref[0, :num_bins] += local
-        max_ref[0, 0] = jnp.maximum(
-            max_ref[0, 0], jnp.max(jnp.where(valid, vals, 0)))
+            count = jnp.sum(((bin_ids == b) & valid).astype(jnp.int32),
+                            keepdims=True)
+            local = jnp.where(out_lane == b, count, local)
+        hist_ref[...] += local
+        max_ref[...] = jnp.maximum(
+            max_ref[...], jnp.max(jnp.where(valid, vals, 0), keepdims=True))
 
     return kernel
 
@@ -62,23 +68,26 @@ def binning_histogram(sizes, *, upper: Tuple[int, ...], num_bins: int,
                       block: int = 1024, interpret: Optional[bool] = None):
     """Pass-1 of the binning method as a Pallas kernel.
 
-    ``interpret=None`` auto-detects (compiled on TPU, interpreted
-    elsewhere).  Returns (bin_size (num_bins,) int32, max_size () int32)."""
+    A grid step classifies ``block`` row sizes, rounded up to whole
+    (8, 128) int32 tiles.  ``interpret=None`` auto-detects (compiled on
+    TPU, interpreted elsewhere).  Returns (bin_size (num_bins,) int32,
+    max_size () int32)."""
     interpret = resolve_interpret(interpret)
+    assert num_bins <= _LANES, num_bins
     m = sizes.shape[0]
-    m_pad = -(-m // block) * block
-    if m_pad != m:
-        sizes = jnp.pad(sizes, (0, m_pad - m))
-    nb_pad = max(num_bins, 8)
-    kernel = _make_kernel(upper, num_bins, block, m)
+    rows = -(-max(block, 8 * _LANES) // (8 * _LANES)) * 8
+    step = rows * _LANES
+    m_pad = -(-m // step) * step
+    sizes = jnp.pad(sizes.astype(jnp.int32), (0, m_pad - m))
+    kernel = _make_kernel(upper, num_bins, rows, m)
     hist, mx = pl.pallas_call(
         kernel,
-        grid=(m_pad // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((1, nb_pad), lambda i: (0, 0)),
+        grid=(m_pad // step,),
+        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((1, _LANES), lambda i: (0, 0)),
                    pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, nb_pad), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((1, _LANES), jnp.int32),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         interpret=interpret,
-    )(sizes.astype(jnp.int32))
+    )(sizes.reshape(-1, _LANES))
     return hist[0, :num_bins], mx[0, 0]

@@ -1,15 +1,19 @@
 """Pallas TPU kernels: per-bin hash-table SpGEMM phases (OpSparse §5.2, §5.6).
 
-One grid step computes ONE output row (kernel1..7 of the paper); the hash
-table lives in VMEM scratch (the analog of the V100's 96 KB shared memory —
-DESIGN.md §2/§5).  Row ids of the bin and the bin's row count arrive via
-scalar prefetch; the CSR arrays stay in HBM (`pl.ANY`) and are loaded with
-dynamic slices.
+Each grid step builds the hash tables of a few output rows (kernel1..7 of
+the paper).  The probe loop is scalar code, and the TPU scalar unit can
+address only SMEM, so the tables live in SMEM (the analog of the V100's
+96 KB shared memory; 1 MiB on a v5e core, which bounds the table rungs).
+Bin row ids and the CSR arrays stay in HBM (``pl.ANY``) and reach SMEM
+through block DMAs of aligned 1024-entry tiles, each kept resident while
+its index stream (rows, a_rpt, A entries, b_rpt, B entries) stays inside
+it.  A dumped table leaves the kernel as a blocked SMEM output covering a
+whole 1-D HBM tile per grid step.
 
 Probe disciplines (paper §5.2, Fig. 9):
   * ``single_access=True``  — Algorithms 4/5: ONE table transaction per
     probe iteration.  On GPU this is the swapped-`atomicCAS` trick; a Pallas
-    grid step owns its row's table, so the same discipline is a single
+    grid step owns its rows' tables, so the same discipline is a single
     read-modify-write per iteration, no CAS needed.
   * ``single_access=False`` — the nsparse/spECK baseline: check-then-CAS,
     i.e. a second table transaction whenever an empty slot is claimed (and
@@ -42,20 +46,20 @@ builds the (col, val) table ONCE per row and emits nnz, the raw table, and
 the per-row transaction count in one ``pallas_call``; the numeric result
 reuses the symbolic build instead of re-probing, roughly halving per-row
 table transactions (measured by the Fig.-9 access counters, not asserted).
-The two-pass kernels stay as the parity/access-count oracle.
+The two-pass kernels stay as the parity/access-count oracle.  All three
+phases are one kernel factory (``_make_kernel``) with different outputs.
 
 Row packing (paper opt. 3 trade-off, TPU form): a rung whose table is
-smaller than the minimum (8, 128) int32 VMEM tile leaves most of the tile
-idle when one grid step owns one row.  With ``row_packing`` the fused AND
-standalone symbolic kernels pack ``ladder.rows_per_block[b]`` rows per grid
-step as independent sub-tables inside one tile (per-sub-row offsets from
-scalar prefetch), so rung occupancy scales with the tile instead of the
-row.  The two-pass NUMERIC kernels stay unpacked: they dump their raw
+smaller than a (8, 128) int32 tile is laid out ``ladder.rows_per_block[b]``
+rows per tile as independent sub-tables (per-sub-row offsets), so the
+dumped table stride shrinks with the rung instead of padding every row to
+the tile.  The two-pass NUMERIC kernels stay unpacked: they dump their raw
 tables, so packing would change the dumped stride for no occupancy win.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -75,14 +79,9 @@ from repro.kernels import resolve_interpret
 HASH_SCALE = 107  # nsparse's multiplicative constant, kept (§5.2 "same way")
 _PROBE_GUARD_FACTOR = 2  # safety: bail after 2*t_size probes (misuse guard)
 _ROW_BUCKET_MIN = 8      # smallest per-rung row-count bucket
+_BLK = 1024              # 1-D HBM tile: the granule of every operand DMA
 
 INT32_MAX = np.iinfo(np.int32).max
-
-
-def _table_geom(t_size: int) -> Tuple[int, int]:
-    """VMEM scratch geometry: lane-aligned (rows, 128)."""
-    rows = max(1, -(-t_size // 128))
-    return rows, 128
 
 
 def _is_pow2(n: int) -> bool:
@@ -101,31 +100,128 @@ def _hash_next(h, t_size: int):
     return jnp.where(h + 1 < t_size, h + 1, 0)  # mod path (numeric)
 
 
-# ---------------------------------------------------------------------------
-# Symbolic kernel: count distinct column ids per row (no value multiply).
-# ---------------------------------------------------------------------------
+def _packed_geom(t_size: int, pack: int) -> Tuple[int, int]:
+    """Packed table geometry.
 
-def _make_symbolic_kernel(t_size: int, pack: int, single_access: bool):
+    ``pack`` sub-tables of ``t_size`` entries live at stride ``stride``
+    inside one lane-aligned (t_rows, 128) tile; returns (t_rows, stride).
+    ``pack`` must be a power of two <= 128 so the tile splits evenly.
+    """
+    assert pack >= 1 and pack & (pack - 1) == 0 and pack <= 128, pack
+    t_rows = max(1, -(-(pack * t_size) // 128))
+    flat = t_rows * 128
+    assert flat % pack == 0, (t_size, pack)
+    return t_rows, flat // pack
+
+
+def _step_geom(t_size: int, pack: int, rows_cap: int):
+    """Grid geometry shared by the three kernels.
+
+    One grid step owns ``tiles`` consecutive (t_rows, 128) tiles — enough
+    that a step's flat table span is a whole 1-D HBM tile (_BLK entries),
+    which is what a blocked SMEM output may cover.  Returns ``(stride,
+    rps, steps)``: the per-row table stride, rows per grid step and the
+    number of grid steps (``steps * rps >= rows_cap``; surplus rows are
+    masked like any padding row).
+    """
+    assert rows_cap % pack == 0, (rows_cap, pack)
     t_rows, stride = _packed_geom(t_size, pack)
+    tiles = 8 // math.gcd(t_rows, 8)
+    rps = tiles * pack
+    return stride, rps, -(-rows_cap // rps)
+
+
+def _pad_blocks(x):
+    """Pad a 1-D operand to whole _BLK blocks so every block DMA is in
+    bounds on every backend."""
+    pad = -x.shape[0] % _BLK
+    return jnp.pad(x, (0, pad)) if pad else x
+
+
+# ---------------------------------------------------------------------------
+# The hash kernel.  One factory serves the three phases:
+#   symbolic  (values=False, emit_nnz=True):  per-row nnz + accesses;
+#   numeric   (values=True,  emit_nnz=False): dumped (col, val) tables;
+#   fused     (values=True,  emit_nnz=True):  both from ONE build.
+# ---------------------------------------------------------------------------
+
+def _make_kernel(t_size: int, stride: int, rps: int, rows_cap: int, *,
+                 values: bool, emit_nnz: bool, single_access: bool,
+                 val_dtype):
     guard = _PROBE_GUARD_FACTOR * t_size
+    span = rps * stride                       # table entries per grid step
+    # HBM operands, grouped by the index stream that reads them: rows,
+    # a_rpt, A entries (col[, val]), b_rpt, B entries (col[, val]).  A
+    # group shares one block tag, and a miss fetches its members at once.
+    w = 2 if values else 1
+    groups = ((0,), (1,), tuple(range(2, 2 + w)), (2 + w,),
+              tuple(range(3 + w, 3 + 2 * w)))
+    ROWS, ARPT, AENT, BRPT, BENT = range(5)
+    n_src = 3 + 2 * w
 
-    def kernel(rows_smem, count_smem, a_rpt, a_col, b_rpt, b_col,
-               nnz_out, acc_out, table):
-        i = pl.program_id(0)
-        # One fresh tile per grid step (the paper re-initializes per thread
-        # block); sub-row j owns [j*stride, j*stride + t_size) of the
-        # flattened tile — identical to the fused kernel's packing.
-        table[...] = jnp.full((t_rows, 128), -1, jnp.int32)
+    def kernel(count_smem, *refs):
+        zero = jnp.zeros((), val_dtype)
+        src, refs = refs[:n_src], refs[n_src:]
+        n_out = (2 if values else 0) + (1 if emit_nnz else 0) + 1
+        outs, scratch = refs[:n_out], refs[n_out:]
+        if values:                  # the tables are the dumped outputs
+            col_tab, val_tab = outs[0], outs[1]
+            outs = outs[2:]
+        else:
+            col_tab, scratch = scratch[0], scratch[1:]
+            val_tab = None
+        nnz_out = outs[0] if emit_nnz else None
+        acc_out = outs[-1]
+        bufs, (tags, sems) = scratch[:n_src], scratch[n_src:]
+        step = pl.program_id(0)
 
-        for j in range(pack):           # static unroll over the sub-tables
-            idx = i * pack + j
+        @pl.when(step == 0)
+        def _():
+            for g in range(len(groups)):
+                tags[g] = -1
+
+        def read(g: int, idx):
+            """``src[s][idx]`` for every member ``s`` of group ``g``,
+            through a one-block SMEM cache: scalar loads cannot address
+            HBM, so the aligned _BLK block holding ``idx`` is DMA'd in
+            first unless it is already resident."""
+            blk = idx // _BLK
+
+            @pl.when(tags[g] != blk)
+            def _():
+                start = pl.multiple_of(blk * _BLK, _BLK)
+                cps = [pltpu.make_async_copy(src[s].at[pl.ds(start, _BLK)],
+                                             bufs[s], sems.at[i])
+                       for i, s in enumerate(groups[g])]
+                for cp in cps:
+                    cp.start()
+                for cp in cps:
+                    cp.wait()
+                tags[g] = blk
+
+            vals = tuple(bufs[s][idx % _BLK] for s in groups[g])
+            return vals if len(vals) > 1 else vals[0]
+
+        # One fresh table span per grid step (the paper re-initializes per
+        # thread block); row j of the step owns [j*stride, j*stride+t_size).
+        def clear(t, carry):
+            col_tab[t] = jnp.int32(-1)
+            if values:
+                val_tab[t] = zero
+            return carry
+
+        jax.lax.fori_loop(0, span, clear, 0)
+
+        def row(j, carry):
+            idx = step * rps + j
             active = idx < count_smem[0]
-            r = rows_smem[idx]
+            r = jnp.where(active, read(ROWS, jnp.minimum(idx, rows_cap - 1)),
+                          0)
             base = j * stride
-            a_lo = jnp.where(active, a_rpt[r], 0)
-            a_hi = jnp.where(active, a_rpt[r + 1], 0)
+            a_lo = jnp.where(active, read(ARPT, r), 0)
+            a_hi = jnp.where(active, read(ARPT, r + 1), 0)
 
-            def insert(key, carry, base=base):
+            def insert(key, prod, carry):
                 nnz, acc = carry
                 h0 = _hash_init(key, t_size)
 
@@ -133,29 +229,36 @@ def _make_symbolic_kernel(t_size: int, pack: int, single_access: bool):
                     h, done, ins, probes = st
                     return (~done) & (probes < guard)
 
+                def add_val(slot, hit):
+                    if values:
+                        val_tab[slot] = val_tab[slot] + jnp.where(
+                            hit, prod, zero)
+
                 if single_access:
+                    # Alg 4/5 discipline: ONE col-table transaction per
+                    # probe iteration; value touched on the terminal one.
                     def body(st):
                         h, done, ins, probes = st
                         slot = base + h
-                        hr, hl = slot // 128, slot % 128
-                        cur = table[hr, hl]                   # 1 transaction
+                        cur = col_tab[slot]                   # 1 transaction
                         empty = cur == -1
-                        table[hr, hl] = jnp.where(empty, key, cur)
                         hit = empty | (cur == key)
+                        col_tab[slot] = jnp.where(empty, key, cur)
+                        add_val(slot, hit)
                         return (_hash_next(h, t_size), hit, ins | empty,
                                 probes + 1)
                 else:
+                    # nsparse-style check-then-CAS baseline: a separate
+                    # transaction claims the empty slot (read-again-and-write).
                     def body(st):
                         h, done, ins, probes = st
                         slot = base + h
-                        hr, hl = slot // 128, slot % 128
-                        cur = table[hr, hl]                   # transaction 1
+                        cur = col_tab[slot]                   # transaction 1
                         empty = cur == -1
-                        # nsparse-style: a separate CAS transaction claims
-                        # the empty slot (read-again-and-write).
-                        cur2 = jnp.where(empty, table[hr, hl], cur)  # 2
-                        table[hr, hl] = jnp.where(empty, key, cur2)
+                        cur2 = jnp.where(empty, col_tab[slot], cur)  # 2
+                        col_tab[slot] = jnp.where(empty, key, cur2)
                         hit = empty | (cur == key)
+                        add_val(slot, hit)
                         return (_hash_next(h, t_size), hit, ins | empty,
                                 probes +
                                 jnp.where(empty, 2, 1).astype(jnp.int32))
@@ -166,22 +269,83 @@ def _make_symbolic_kernel(t_size: int, pack: int, single_access: bool):
                 return nnz + ins.astype(jnp.int32), acc + probes
 
             def outer(e, carry):
-                k = a_col[a_lo + e]
-                b_lo = b_rpt[k]
-                b_hi = b_rpt[k + 1]
+                k, av = read(AENT, a_lo + e) if values else (
+                    read(AENT, a_lo + e), None)
+                b_lo = read(BRPT, k)
+                b_hi = read(BRPT, k + 1)
 
                 def inner(jj, carry):
-                    c = b_col[b_lo + jj]
-                    return insert(c, carry)
+                    if values:
+                        c, bv = read(BENT, b_lo + jj)
+                        return insert(c, av * bv, carry)
+                    return insert(read(BENT, b_lo + jj), None, carry)
 
                 return jax.lax.fori_loop(0, b_hi - b_lo, inner, carry)
 
             nnz, acc = jax.lax.fori_loop(0, a_hi - a_lo, outer,
                                          (jnp.int32(0), jnp.int32(0)))
-            nnz_out[j] = jnp.where(active, nnz, 0)
-            acc_out[j] = jnp.where(active, acc, 0)
+            if emit_nnz:
+                nnz_out[0, j] = jnp.where(active, nnz, 0)
+            acc_out[0, j] = jnp.where(active, acc, 0)
+            return carry
+
+        jax.lax.fori_loop(0, rps, row, 0)
 
     return kernel
+
+
+def _hash_call(rows, count, operands, *, t_size: int, rows_cap: int,
+               pack: int, emit_nnz: bool, single_access: bool,
+               interpret: Optional[bool]):
+    """Build and run one hash ``pallas_call`` over one bin.
+
+    Tables, block caches and the per-row counters live in SMEM: the
+    probe loop is scalar code, and the TPU scalar unit addresses SMEM
+    only.  The CSR operands stay in HBM and reach the kernel through
+    aligned block DMAs.  Returns the flat outputs trimmed to
+    ``rows_cap`` rows: ``[col_tabs, val_tabs]`` (rows_cap, stride) when
+    the operands carry values, ``[nnz]`` when ``emit_nnz``, then
+    ``accesses``.
+    """
+    interpret = resolve_interpret(interpret)
+    stride, rps, steps = _step_geom(t_size, pack, rows_cap)
+    values = len(operands) == 6             # (a_rpt, a_col, [a_val], ...)
+    val_dtype = operands[2].dtype if values else jnp.int32
+    srcs = [_pad_blocks(x) for x in (rows, *operands)]
+    span = rps * stride
+    smem_blk = lambda n: pl.BlockSpec((n,), lambda i, cnt: (i,),
+                                      memory_space=pltpu.SMEM)
+    row_blk = pl.BlockSpec((None, 1, rps), lambda i, cnt: (i, 0, 0),
+                           memory_space=pltpu.SMEM)
+    out_specs, out_shape = [], []
+    if values:
+        out_specs += [smem_blk(span), smem_blk(span)]
+        out_shape += [jax.ShapeDtypeStruct((steps * span,), jnp.int32),
+                      jax.ShapeDtypeStruct((steps * span,), val_dtype)]
+    n_row_outs = 2 if emit_nnz else 1           # [nnz,] accesses
+    out_specs += [row_blk] * n_row_outs
+    out_shape += [jax.ShapeDtypeStruct((steps, 1, rps), jnp.int32)
+                  ] * n_row_outs
+    scratch = [] if values else [pltpu.SMEM((span,), jnp.int32)]
+    scratch += [pltpu.SMEM((_BLK,), x.dtype) for x in srcs]
+    scratch += [pltpu.SMEM((5,), jnp.int32),        # block tag per group
+                pltpu.SemaphoreType.DMA((2,))]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(srcs),
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    kernel = _make_kernel(t_size, stride, rps, rows_cap, values=values,
+                          emit_nnz=emit_nnz, single_access=single_access,
+                          val_dtype=val_dtype)
+    outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                          interpret=interpret)(count, *srcs)
+    tables = [t.reshape(steps * rps, stride)[:rows_cap]
+              for t in outs[:2 if values else 0]]
+    per_row = [c.reshape(-1)[:rows_cap] for c in outs[2 if values else 0:]]
+    return tables + per_row
 
 
 @functools.partial(
@@ -195,112 +359,15 @@ def symbolic_bin_call(rows, count, a_rpt, a_col, b_rpt, b_col, *,
     """Run the symbolic hash kernel over one bin.
 
     rows:  (rows_cap,) int32 row ids (padded); count: (1,) int32 valid rows.
-    One grid step counts ``pack`` rows as sub-tables of one VMEM tile
-    (``pack=1`` reproduces the one-row-per-step layout).
+    ``pack`` rows share one (t_rows, 128) tile as sub-tables (``pack=1``
+    reproduces the one-table-per-row layout).
     Returns (nnz, accesses): both (rows_cap,) int32.
     """
-    interpret = resolve_interpret(interpret)
-    assert rows_cap % pack == 0, (rows_cap, pack)
-    t_rows, _ = _packed_geom(t_size, pack)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(rows_cap // pack,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
-        out_specs=[
-            pl.BlockSpec((pack,), lambda i, rows, cnt: (i,)),
-            pl.BlockSpec((pack,), lambda i, rows, cnt: (i,)),
-        ],
-        scratch_shapes=[pltpu.VMEM((t_rows, 128), jnp.int32)],
-    )
-    kernel = _make_symbolic_kernel(t_size, pack, single_access)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_cap,), jnp.int32),
-            jax.ShapeDtypeStruct((rows_cap,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rows, count, a_rpt, a_col, b_rpt, b_col)
-
-
-# ---------------------------------------------------------------------------
-# Numeric kernel: accumulate values per row into (col, val) hash tables.
-# ---------------------------------------------------------------------------
-
-def _make_numeric_kernel(t_size: int, single_access: bool, val_dtype):
-    t_rows, t_lanes = _table_geom(t_size)
-    guard = _PROBE_GUARD_FACTOR * t_size
-
-    def kernel(rows_smem, count_smem, a_rpt, a_col, a_val, b_rpt, b_col,
-               b_val, col_out, val_out, acc_out, col_tab, val_tab):
-        i = pl.program_id(0)
-        active = i < count_smem[0]
-        r = rows_smem[i]
-        col_tab[...] = jnp.full((t_rows, t_lanes), -1, jnp.int32)
-        val_tab[...] = jnp.zeros((t_rows, t_lanes), val_dtype)
-        a_lo = jnp.where(active, a_rpt[r], 0)
-        a_hi = jnp.where(active, a_rpt[r + 1], 0)
-
-        def insert(key, prod, acc):
-            h0 = _hash_init(key, t_size)
-
-            def cond(st):
-                h, done, probes = st
-                return (~done) & (probes < guard)
-
-            if single_access:
-                # Alg 5: one col-table transaction per iteration; the value
-                # slot is touched only on the terminal iteration.
-                def body(st):
-                    h, done, probes = st
-                    hr, hl = h // 128, h % 128
-                    cur = col_tab[hr, hl]                     # 1 transaction
-                    empty = cur == -1
-                    hit = empty | (cur == key)
-                    col_tab[hr, hl] = jnp.where(empty, key, cur)
-                    val_tab[hr, hl] = val_tab[hr, hl] + jnp.where(
-                        hit, prod, jnp.zeros((), val_dtype))
-                    return (_hash_next(h, t_size), hit, probes + 1)
-            else:
-                # nsparse-style: read, branch, then CAS-claim (second
-                # transaction) when the slot was empty.
-                def body(st):
-                    h, done, probes = st
-                    hr, hl = h // 128, h % 128
-                    cur = col_tab[hr, hl]                     # transaction 1
-                    empty = cur == -1
-                    cur2 = jnp.where(empty, col_tab[hr, hl], cur)  # transaction 2
-                    col_tab[hr, hl] = jnp.where(empty, key, cur2)
-                    hit = empty | (cur == key)
-                    val_tab[hr, hl] = val_tab[hr, hl] + jnp.where(
-                        hit, prod, jnp.zeros((), val_dtype))
-                    return (_hash_next(h, t_size), hit,
-                            probes + jnp.where(empty, 2, 1).astype(jnp.int32))
-
-            h, done, probes = jax.lax.while_loop(
-                cond, body, (h0, jnp.asarray(False), jnp.int32(0)))
-            return acc + probes
-
-        def outer(e, acc):
-            k = a_col[a_lo + e]
-            av = a_val[a_lo + e]
-            b_lo = b_rpt[k]
-            b_hi = b_rpt[k + 1]
-
-            def inner(j, acc):
-                c = b_col[b_lo + j]
-                bv = b_val[b_lo + j]
-                return insert(c, av * bv, acc)
-
-            return jax.lax.fori_loop(0, b_hi - b_lo, inner, acc)
-
-        acc = jax.lax.fori_loop(0, a_hi - a_lo, outer, jnp.int32(0))
-        col_out[0, :] = col_tab[...].reshape(-1)
-        val_out[0, :] = val_tab[...].reshape(-1)
-        acc_out[0] = jnp.where(active, acc, 0)
-
-    return kernel
+    nnz, acc = _hash_call(
+        rows, count, (a_rpt, a_col, b_rpt, b_col), t_size=t_size,
+        rows_cap=rows_cap, pack=pack, emit_nnz=True,
+        single_access=single_access, interpret=interpret)
+    return nnz, acc
 
 
 @functools.partial(
@@ -309,150 +376,17 @@ def _make_numeric_kernel(t_size: int, single_access: bool, val_dtype):
 def numeric_bin_call(rows, count, a_rpt, a_col, a_val, b_rpt, b_col, b_val,
                      *, t_size: int, rows_cap: int, single_access: bool,
                      interpret: Optional[bool] = None):
-    """Run the numeric hash kernel over one bin.
+    """Run the numeric hash kernel over one bin (one table per row).
 
     Returns (col_tabs, val_tabs, accesses):
       col_tabs (rows_cap, t_pad) int32 — raw hash tables (-1 = empty);
       val_tabs (rows_cap, t_pad);  accesses (rows_cap,) int32.
     """
-    interpret = resolve_interpret(interpret)
-    t_rows, t_lanes = _table_geom(t_size)
-    t_pad = t_rows * t_lanes
-    val_dtype = a_val.dtype
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(rows_cap,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 6,
-        out_specs=[
-            pl.BlockSpec((1, t_pad), lambda i, rows, cnt: (i, 0)),
-            pl.BlockSpec((1, t_pad), lambda i, rows, cnt: (i, 0)),
-            pl.BlockSpec((1,), lambda i, rows, cnt: (i,)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((t_rows, t_lanes), jnp.int32),
-            pltpu.VMEM((t_rows, t_lanes), val_dtype),
-        ],
-    )
-    kernel = _make_numeric_kernel(t_size, single_access, val_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_cap, t_pad), jnp.int32),
-            jax.ShapeDtypeStruct((rows_cap, t_pad), val_dtype),
-            jax.ShapeDtypeStruct((rows_cap,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rows, count, a_rpt, a_col, a_val, b_rpt, b_col, b_val)
-
-
-# ---------------------------------------------------------------------------
-# Fused symbolic->numeric kernel: ONE table build per row emits nnz AND the
-# accumulated (col, val) table — with optional multi-row VMEM packing.
-# ---------------------------------------------------------------------------
-
-def _packed_geom(t_size: int, pack: int) -> Tuple[int, int]:
-    """Packed VMEM scratch geometry.
-
-    ``pack`` sub-tables of ``t_size`` entries live at stride ``stride``
-    inside one lane-aligned (t_rows, 128) tile; returns (t_rows, stride).
-    ``pack`` must be a power of two <= 128 so the tile splits evenly.
-    """
-    assert pack >= 1 and pack & (pack - 1) == 0 and pack <= 128, pack
-    t_rows = max(1, -(-(pack * t_size) // 128))
-    flat = t_rows * 128
-    assert flat % pack == 0, (t_size, pack)
-    return t_rows, flat // pack
-
-
-def _make_fused_kernel(t_size: int, pack: int, single_access: bool,
-                       val_dtype):
-    t_rows, stride = _packed_geom(t_size, pack)
-    guard = _PROBE_GUARD_FACTOR * t_size
-
-    def kernel(rows_smem, count_smem, a_rpt, a_col, a_val, b_rpt, b_col,
-               b_val, nnz_out, col_out, val_out, acc_out, col_tab, val_tab):
-        i = pl.program_id(0)
-        # One fresh tile per grid step; sub-row j owns the slice
-        # [j*stride, j*stride + t_size) of the flattened tile.
-        col_tab[...] = jnp.full((t_rows, 128), -1, jnp.int32)
-        val_tab[...] = jnp.zeros((t_rows, 128), val_dtype)
-
-        for j in range(pack):           # static unroll over the sub-tables
-            idx = i * pack + j
-            active = idx < count_smem[0]
-            r = rows_smem[idx]
-            base = j * stride
-            a_lo = jnp.where(active, a_rpt[r], 0)
-            a_hi = jnp.where(active, a_rpt[r + 1], 0)
-
-            def insert(key, prod, carry, base=base):
-                nnz, acc = carry
-                h0 = _hash_init(key, t_size)
-
-                def cond(st):
-                    h, done, ins, probes = st
-                    return (~done) & (probes < guard)
-
-                if single_access:
-                    # Alg 4/5 discipline: ONE col-table transaction per
-                    # probe iteration; value touched on the terminal one.
-                    def body(st):
-                        h, done, ins, probes = st
-                        slot = base + h
-                        hr, hl = slot // 128, slot % 128
-                        cur = col_tab[hr, hl]                 # 1 transaction
-                        empty = cur == -1
-                        hit = empty | (cur == key)
-                        col_tab[hr, hl] = jnp.where(empty, key, cur)
-                        val_tab[hr, hl] = val_tab[hr, hl] + jnp.where(
-                            hit, prod, jnp.zeros((), val_dtype))
-                        return (_hash_next(h, t_size), hit, ins | empty,
-                                probes + 1)
-                else:
-                    # nsparse-style check-then-CAS baseline.
-                    def body(st):
-                        h, done, ins, probes = st
-                        slot = base + h
-                        hr, hl = slot // 128, slot % 128
-                        cur = col_tab[hr, hl]                 # transaction 1
-                        empty = cur == -1
-                        cur2 = jnp.where(empty, col_tab[hr, hl], cur)  # 2
-                        col_tab[hr, hl] = jnp.where(empty, key, cur2)
-                        hit = empty | (cur == key)
-                        val_tab[hr, hl] = val_tab[hr, hl] + jnp.where(
-                            hit, prod, jnp.zeros((), val_dtype))
-                        return (_hash_next(h, t_size), hit, ins | empty,
-                                probes +
-                                jnp.where(empty, 2, 1).astype(jnp.int32))
-
-                h, done, ins, probes = jax.lax.while_loop(
-                    cond, body, (h0, jnp.asarray(False), jnp.asarray(False),
-                                 jnp.int32(0)))
-                return nnz + ins.astype(jnp.int32), acc + probes
-
-            def outer(e, carry):
-                k = a_col[a_lo + e]
-                av = a_val[a_lo + e]
-                b_lo = b_rpt[k]
-                b_hi = b_rpt[k + 1]
-
-                def inner(jj, carry):
-                    c = b_col[b_lo + jj]
-                    bv = b_val[b_lo + jj]
-                    return insert(c, av * bv, carry)
-
-                return jax.lax.fori_loop(0, b_hi - b_lo, inner, carry)
-
-            nnz, acc = jax.lax.fori_loop(0, a_hi - a_lo, outer,
-                                         (jnp.int32(0), jnp.int32(0)))
-            nnz_out[j] = jnp.where(active, nnz, 0)
-            acc_out[j] = jnp.where(active, acc, 0)
-
-        col_out[...] = col_tab[...].reshape(pack, stride)
-        val_out[...] = val_tab[...].reshape(pack, stride)
-
-    return kernel
+    col, val, acc = _hash_call(
+        rows, count, (a_rpt, a_col, a_val, b_rpt, b_col, b_val),
+        t_size=t_size, rows_cap=rows_cap, pack=1, emit_nnz=False,
+        single_access=single_access, interpret=interpret)
+    return col, val, acc
 
 
 @functools.partial(
@@ -464,45 +398,19 @@ def fused_bin_call(rows, count, a_rpt, a_col, a_val, b_rpt, b_col, b_val,
                    single_access: bool = True, interpret: Optional[bool] = None):
     """Run the fused symbolic->numeric hash kernel over one bin.
 
-    One grid step builds ``pack`` rows' tables as sub-tables of one VMEM
-    tile (``pack=1`` reproduces the one-row-per-step layout).  Returns
+    ``pack`` rows' tables share one (t_rows, 128) tile as sub-tables
+    (``pack=1`` reproduces the one-table-per-row layout).  Returns
     ``(nnz, col_tabs, val_tabs, accesses)``:
       nnz      (rows_cap,) int32 — distinct columns per row;
       col_tabs (rows_cap, stride) int32 — raw per-row tables (-1 empty);
       val_tabs (rows_cap, stride) — accumulated values;
       accesses (rows_cap,) int32 — per-row table transactions.
     """
-    interpret = resolve_interpret(interpret)
-    assert rows_cap % pack == 0, (rows_cap, pack)
-    t_rows, stride = _packed_geom(t_size, pack)
-    val_dtype = a_val.dtype
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(rows_cap // pack,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 6,
-        out_specs=[
-            pl.BlockSpec((pack,), lambda i, rows, cnt: (i,)),
-            pl.BlockSpec((pack, stride), lambda i, rows, cnt: (i, 0)),
-            pl.BlockSpec((pack, stride), lambda i, rows, cnt: (i, 0)),
-            pl.BlockSpec((pack,), lambda i, rows, cnt: (i,)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((t_rows, 128), jnp.int32),
-            pltpu.VMEM((t_rows, 128), val_dtype),
-        ],
-    )
-    kernel = _make_fused_kernel(t_size, pack, single_access, val_dtype)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_cap,), jnp.int32),
-            jax.ShapeDtypeStruct((rows_cap, stride), jnp.int32),
-            jax.ShapeDtypeStruct((rows_cap, stride), val_dtype),
-            jax.ShapeDtypeStruct((rows_cap,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rows, count, a_rpt, a_col, a_val, b_rpt, b_col, b_val)
+    col, val, nnz, acc = _hash_call(
+        rows, count, (a_rpt, a_col, a_val, b_rpt, b_col, b_val),
+        t_size=t_size, rows_cap=rows_cap, pack=pack, emit_nnz=True,
+        single_access=single_access, interpret=interpret)
+    return nnz, col, val, acc
 
 
 # ---------------------------------------------------------------------------

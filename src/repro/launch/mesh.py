@@ -14,7 +14,13 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
+
+
+def _auto(n: int) -> tuple:
+    """Auto axis types: ``with_sharding_constraint`` refuses Explicit axes,
+    which ``jax.make_mesh`` now defaults to."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def data_axes(mesh) -> tuple:
@@ -48,4 +54,5 @@ def dp_size(mesh) -> int:
 def make_host_mesh(model_axis: int = 1):
     """A tiny mesh over the real local devices (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=_auto(2))

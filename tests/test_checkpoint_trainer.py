@@ -57,8 +57,6 @@ def test_checkpoint_elastic_resharding(tmp_path):
     """Restore under a different sharding (elastic restart path)."""
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     ckpt.save(tmp_path, 1, tree)
-    # axis_types/AxisType only exists on newer JAX; default axis types are
-    # what we want on every version.
     mesh = jax.make_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("data", None))
