@@ -57,13 +57,13 @@ and bitwise result parity across every request.  Records an
 ``_estimate``-suffixed trajectory key with the cold-phase breakdown.
 
 ``--trace PATH`` enables the engine's structured telemetry layer
-(``repro.engine.telemetry``) for the whole run and exports the span log
-as a schema-validated Chrome ``trace_event`` file at PATH (plus a JSONL
-event log alongside) — load it in Perfetto / ``chrome://tracing`` to see
-cold vs steady requests and the sharded fan-out.  Traced runs record
-under a ``_traced``-suffixed trajectory key and gate their steady-state
-latency at <5% over the tracing-disabled baseline for the same
-configuration (the observability tax must stay in the noise).
+(``repro.engine.telemetry``) for the whole run, checks that its spans
+cover the full nested pipeline, and exports the event log as JSON Lines
+at PATH.  (A profiler trace, ``jax.profiler.trace``, holds the same spans
+as ``opsparse.*`` annotations on the device trace's clock.)  Traced runs
+record under a ``_traced``-suffixed trajectory key and gate their
+steady-state latency at <5% over the tracing-disabled baseline for the
+same configuration (the observability tax must stay in the noise).
 
 Every run also records a perf-trajectory artifact at the repo root
 (``BENCH_engine.json``): per-configuration steady-state latency (mean
@@ -74,7 +74,7 @@ table-access totals, so future PRs have a baseline to compare against.
 
 Run:  PYTHONPATH=src python benchmarks/bench_engine.py [--smoke]
           [--method hash] [--fused] [--adaptive] [--shards 2]
-          [--trace /tmp/trace.json]
+          [--trace /tmp/events.jsonl]
 """
 from __future__ import annotations
 
@@ -93,7 +93,7 @@ from repro.core.analysis import exclusive_sum_in_place
 from repro.core.faults import FaultPlan, FaultSpec
 from repro.engine import (AdaptivePolicy, Arena, MatrixSig, MemoryGovernor,
                           SpgemmEngine, Telemetry, git_rev, total_traces,
-                          utc_now_iso, validate_chrome_trace)
+                          utc_now_iso)
 from repro.kernels import spgemm_hash, use_compile_cache
 from repro.serve import SpgemmService
 
@@ -647,11 +647,10 @@ def main(argv=None):
     ap.add_argument("--check", action="store_true",
                     help="verify every result against the dense oracle")
     ap.add_argument("--trace", metavar="PATH", default=None,
-                    help="enable telemetry and export a schema-validated "
-                         "Chrome trace_event file to PATH (+ a .jsonl "
-                         "event log alongside); gates traced steady "
-                         "latency at <5%% over the tracing-disabled "
-                         "baseline in BENCH_engine.json")
+                    help="enable telemetry, check its spans cover the "
+                         "pipeline and export the event log as JSON Lines "
+                         "to PATH; gates traced steady latency at <5%% "
+                         "over tracing off in the same process")
     args = ap.parse_args(argv)
     use_compile_cache()
     if args.requests < 1:
@@ -696,7 +695,7 @@ def main(argv=None):
 
     stream = build_stream(args.requests, args.m, args.k, args.n, args.avg)
     # --trace flips the engine's telemetry layer on for the WHOLE stream
-    # (cold calls included: the Perfetto view's point is cold vs steady).
+    # (cold calls included: the span check covers cold and steady paths).
     # The ring is sized to hold a full run so the export isn't truncated.
     telemetry = (Telemetry(enabled=True, events_capacity=1 << 16)
                  if args.trace else None)
@@ -879,11 +878,8 @@ def main(argv=None):
     trace_ok = True
     overhead_ok = True
     if args.trace:
-        trace_path = Path(args.trace)
-        telemetry.export_chrome_trace(trace_path)
-        jsonl_path = trace_path.with_suffix(".jsonl")
+        jsonl_path = Path(args.trace)
         n_jsonl = telemetry.export_jsonl(jsonl_path)
-        n_events = validate_chrome_trace(trace_path)   # raises on bad schema
         spans = telemetry.finished_spans()
         names = {s["name"] for s in spans}
         # The acceptance trace must show the full nested pipeline.
@@ -898,8 +894,7 @@ def main(argv=None):
         for s in spans:
             agg[s["name"]] = agg.get(s["name"], 0.0) + s["dur"]
         phases_ms = {n: round(t * 1e3, 3) for n, t in sorted(agg.items())}
-        print(f"trace:         {n_events} trace_event records -> "
-              f"{trace_path} (+{n_jsonl} JSONL rows), "
+        print(f"trace:         {n_jsonl} JSONL rows -> {jsonl_path}, "
               f"{telemetry.events.dropped} ring overflows"
               + ("" if trace_ok else f"; MISSING spans {missing}"))
         # Overhead gate: tracing must add <5% to steady-state latency.

@@ -15,6 +15,10 @@ serves as
 Shapes are static: the expansion size is a host-chosen bucket
 ``prod_capacity >= total_nprod`` (pow-2 bucketing, see ``spgemm.py``);
 padding products carry row id M / col id N and sort to the end.
+
+Device scopes (``repro.phases``): the three products run under
+``opsparse.esc.compress``, with the expansion and the sort inside it
+under ``opsparse.esc.expand`` and ``opsparse.esc.sort``.
 """
 from __future__ import annotations
 
@@ -24,11 +28,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import phases
+
 from .csr import CSR
 from .analysis import nprod_per_entry
 
 
 @partial(jax.jit, static_argnames=("prod_capacity", "with_values"))
+@phases.scope(phases.ESC_EXPAND)
 def expand_products(A: CSR, B: CSR, *, prod_capacity: int,
                     with_values: bool = True):
     """Enumerate all intermediate products of C = A·B, row-major.
@@ -69,6 +76,7 @@ def expand_products(A: CSR, B: CSR, *, prod_capacity: int,
     return rows, cols, vals, valid
 
 
+@phases.scope(phases.ESC_SORT)
 def _sort_products(rows, cols, vals):
     """Stable (row, col) sort.  Two-key lexsort avoids 64-bit keys (the
     fused key row*N+col overflows int32 for the paper's large matrices)."""
@@ -79,6 +87,7 @@ def _sort_products(rows, cols, vals):
 
 
 @partial(jax.jit, static_argnames=("prod_capacity",))
+@phases.scope(phases.ESC_COMPRESS)
 def symbolic(A: CSR, B: CSR, *, prod_capacity: int) -> jax.Array:
     """Symbolic phase: (M+1,) buffer with n_nz per row in [0:M] (rpt reuse).
 
@@ -96,6 +105,7 @@ def symbolic(A: CSR, B: CSR, *, prod_capacity: int) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("prod_capacity", "nnz_capacity"))
+@phases.scope(phases.ESC_COMPRESS)
 def numeric(A: CSR, B: CSR, rpt: jax.Array, *, prod_capacity: int,
             nnz_capacity: int) -> CSR:
     """Numeric phase: fill C.col / C.val given the symbolic-phase ``rpt``.
@@ -123,6 +133,7 @@ def numeric(A: CSR, B: CSR, rpt: jax.Array, *, prod_capacity: int,
 
 
 @partial(jax.jit, static_argnames=("prod_capacity", "nnz_capacity"))
+@phases.scope(phases.ESC_COMPRESS)
 def spgemm_fused(A: CSR, B: CSR, *, prod_capacity: int,
                  nnz_capacity: int) -> CSR:
     """One-pass ESC SpGEMM (expand once, derive rpt AND values).

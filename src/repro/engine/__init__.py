@@ -20,9 +20,10 @@ API and the serving/analytics front-ends:
                  sharded fan-out; ``execute`` backs ``spgemm()``.
   stats.py     — trace accounting and registry-backed engine/plan
                  counters (one source of truth with telemetry.py).
-  telemetry.py — structured spans, metrics registry, ring-buffer event
-                 log, and the JSONL / Chrome trace_event / Prometheus
-                 exporters.
+  telemetry.py — structured spans (also profiler annotations), the
+                 phase vocabulary of the device scopes, metrics
+                 registry, ring-buffer event log, and the JSONL /
+                 Prometheus exporters.
 
 Lifecycle::
 
@@ -54,8 +55,7 @@ from .stats import (EngineStats, PlanStats, plan_label, render,
 from .telemetry import (LATENCY_BUCKETS_S, EventLog, MetricsRegistry, Span,
                         Telemetry, engine_sample_blocks, git_rev,
                         histogram_quantile, merge_sample_blocks,
-                        prometheus_text, resolve_telemetry, utc_now_iso,
-                        validate_chrome_trace)
+                        prometheus_text, resolve_telemetry, utc_now_iso)
 
 __all__ = [
     "AUTO_SHARDS", "AdaptivePolicy", "EstimatorState", "PolicyState",
@@ -70,5 +70,5 @@ __all__ = [
     "LATENCY_BUCKETS_S", "EventLog", "MetricsRegistry", "Span", "Telemetry",
     "engine_sample_blocks", "git_rev", "histogram_quantile",
     "merge_sample_blocks", "prometheus_text", "resolve_telemetry",
-    "utc_now_iso", "validate_chrome_trace",
+    "utc_now_iso",
 ]

@@ -46,6 +46,7 @@ from typing import Callable, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.core import esc
 from repro.core.analysis import (estimate_result, exclusive_sum_in_place,
                                  nprod_into_rpt, row_flops)
@@ -84,8 +85,11 @@ class StepTimer:
     With an ENABLED ``tracer`` each measured step also emits a telemetry
     span (nested under the tracer's current ``with``-span — the cold
     ``cold_steps`` span in practice), giving the trace per-kernel-phase
-    attribution on exactly the paths that already host-sync.  The
-    ``timings`` dict keeps its historical block-time-only semantics.
+    attribution on exactly the paths that already host-sync.  A step that
+    is one phase takes the phase's name (``repro.phases``: ``nprod``,
+    ``bin``, ``rowptr``); ``symbolic`` and ``numeric`` span several.  The
+    ``timings`` dict keeps its historical block-time-only semantics (the
+    two binnings add up under ``bin``).
     """
 
     def __init__(self, enabled: bool, tracer: Optional[Telemetry] = None,
@@ -151,13 +155,13 @@ def _execute_steps(A: CSR, B: CSR, plan: SpgemmPlan,
 
     # ---- step1: setup -----------------------------------------------------
     rpt_buf = nprod_into_rpt(A, B)               # n_prod lives in C.rpt (§5.3)
-    timer.measure("setup", rpt_buf)
+    timer.measure(phases.NPROD, rpt_buf)
     nprod = rpt_buf[:m]
     total_nprod = int(jnp.sum(nprod))            # host sync #1 (sizes launches)
 
     # ---- step2: symbolic binning -------------------------------------------
     sym_binning = bin_rows_for_ladder(nprod, sym_ladder)
-    timer.measure("symbolic_binning", sym_binning.bins)
+    timer.measure(phases.BIN, sym_binning.bins)
 
     prod_capacity = max(plan.prod_bucket or 0,
                         next_bucket(max(int(total_nprod
@@ -198,8 +202,8 @@ def _execute_steps(A: CSR, B: CSR, plan: SpgemmPlan,
                        next_bucket(max(int(total_nnz
                                            * _CAPACITY_HEADROOM), 1)))
     rpt = _exclusive_sum(nnz_buf)                # in-place on the rpt buffer
-    timer.measure("alloc", rpt)
-    timer.measure("numeric_binning", num_binning.bins)
+    timer.measure(phases.ROWPTR, rpt)
+    timer.measure(phases.BIN, num_binning.bins)
 
     # ---- step6: numeric -----------------------------------------------------
     hash_sched = None
@@ -282,15 +286,19 @@ def _build_hot_executable(plan: SpgemmPlan) -> Callable:
 
     def body(A: CSR, B: CSR):
         stats_mod.record_trace(key)      # fires once per trace (recompile)
-        rpt_buf = nprod_into_rpt(A, B)
-        nprod = rpt_buf[:m]
-        total_nprod = jnp.sum(nprod)
-        sym_binning = bin_rows(nprod, upper=sym_upper, num_bins=sym_nb)
+        with phases.scope(phases.NPROD):
+            rpt_buf = nprod_into_rpt(A, B)
+            nprod = rpt_buf[:m]
+            total_nprod = jnp.sum(nprod)
+        with phases.scope(phases.BIN):
+            sym_binning = bin_rows(nprod, upper=sym_upper, num_bins=sym_nb)
         nnz_buf = esc.symbolic(A, B, prod_capacity=prod_cap)
         nnz = nnz_buf[:m]
-        num_binning = bin_rows(nnz, upper=num_upper, num_bins=num_nb)
-        total_nnz = jnp.sum(nnz)
-        rpt = exclusive_sum_in_place(nnz_buf)
+        with phases.scope(phases.BIN):
+            num_binning = bin_rows(nnz, upper=num_upper, num_bins=num_nb)
+        with phases.scope(phases.ROWPTR):
+            total_nnz = jnp.sum(nnz)
+            rpt = exclusive_sum_in_place(nnz_buf)
         if config.fuse_esc:
             C = esc.spgemm_fused(A, B, prod_capacity=prod_cap,
                                  nnz_capacity=nnz_cap)
@@ -300,6 +308,15 @@ def _build_hot_executable(plan: SpgemmPlan) -> Callable:
         return C, total_nprod, total_nnz, sym_binning, num_binning
 
     return _finish_executable(plan, body)
+
+
+def _fallback_nnz(binning, nnz, ladder, row_buckets) -> jax.Array:
+    """nnz of the rows on the ESC fallback rung (0 when the schedule has
+    none): the entries the hash epilogue does not write."""
+    if not row_buckets[-1]:
+        return jnp.int32(0)
+    fallback = binning.bin_of_row == len(ladder.table_sizes)
+    return jnp.sum(jnp.where(fallback, nnz, 0))
 
 
 def _build_hash_executable(plan: SpgemmPlan) -> Callable:
@@ -323,11 +340,13 @@ def _build_hash_executable(plan: SpgemmPlan) -> Callable:
 
     def body(A: CSR, B: CSR):
         stats_mod.record_trace(key)      # fires once per trace (recompile)
-        rpt_buf = nprod_into_rpt(A, B)
-        nprod = rpt_buf[:m]
-        total_nprod = jnp.sum(nprod)
-        sym_binning = bin_rows(nprod, upper=sym_ladder.upper,
-                               num_bins=sym_ladder.num_bins)
+        with phases.scope(phases.NPROD):
+            rpt_buf = nprod_into_rpt(A, B)
+            nprod = rpt_buf[:m]
+            total_nprod = jnp.sum(nprod)
+        with phases.scope(phases.BIN):
+            sym_binning = bin_rows(nprod, upper=sym_ladder.upper,
+                                   num_bins=sym_ladder.num_bins)
         nnz_buf, sym_fall_prod, _ = spgemm_hash.symbolic_scheduled(
             A, B, sym_binning, sym_ladder,
             row_buckets=sched.sym_row_buckets,
@@ -335,10 +354,14 @@ def _build_hash_executable(plan: SpgemmPlan) -> Callable:
             single_access=config.hash_single_access,
             interpret=config.interpret)
         nnz = nnz_buf[:m]
-        num_binning = bin_rows(nnz, upper=num_ladder.upper,
-                               num_bins=num_ladder.num_bins)
-        total_nnz = jnp.sum(nnz)
-        rpt = exclusive_sum_in_place(nnz_buf)
+        with phases.scope(phases.BIN):
+            num_binning = bin_rows(nnz, upper=num_ladder.upper,
+                                   num_bins=num_ladder.num_bins)
+        with phases.scope(phases.ROWPTR):
+            total_nnz = jnp.sum(nnz)
+            fall_nnz = _fallback_nnz(num_binning, nnz, num_ladder,
+                                     sched.num_row_buckets)
+            rpt = exclusive_sum_in_place(nnz_buf)
         # Both phases expand into the SAME shared fallback capacity (one
         # arena bucket, one traced expansion shape per plan).
         C, num_fall_prod, _ = spgemm_hash.numeric_scheduled(
@@ -349,7 +372,7 @@ def _build_hash_executable(plan: SpgemmPlan) -> Callable:
             single_access=config.hash_single_access,
             interpret=config.interpret)
         return (C, total_nprod, total_nnz, sym_binning, num_binning,
-                sym_fall_prod, num_fall_prod)
+                sym_fall_prod, num_fall_prod, fall_nnz)
 
     return _finish_executable(plan, body)
 
@@ -377,11 +400,13 @@ def _build_fused_hash_executable(plan: SpgemmPlan) -> Callable:
 
     def body(A: CSR, B: CSR):
         stats_mod.record_trace(key)      # fires once per trace (recompile)
-        rpt_buf = nprod_into_rpt(A, B)
-        nprod = rpt_buf[:m]
-        total_nprod = jnp.sum(nprod)
-        sym_binning = bin_rows(nprod, upper=sym_ladder.upper,
-                               num_bins=sym_ladder.num_bins)
+        with phases.scope(phases.NPROD):
+            rpt_buf = nprod_into_rpt(A, B)
+            nprod = rpt_buf[:m]
+            total_nprod = jnp.sum(nprod)
+        with phases.scope(phases.BIN):
+            sym_binning = bin_rows(nprod, upper=sym_ladder.upper,
+                                   num_bins=sym_ladder.num_bins)
         C, nnz, sym_fall_prod, _ = spgemm_hash.fused_scheduled(
             A, B, sym_binning, sym_ladder,
             row_buckets=sched.sym_row_buckets,
@@ -390,14 +415,18 @@ def _build_fused_hash_executable(plan: SpgemmPlan) -> Callable:
             single_access=config.hash_single_access,
             interpret=config.interpret,
             row_packing=config.row_packing)
-        total_nnz = jnp.sum(nnz)
+        with phases.scope(phases.ROWPTR):
+            total_nnz = jnp.sum(nnz)
+            fall_nnz = _fallback_nnz(sym_binning, nnz, sym_ladder,
+                                     sched.sym_row_buckets)
         # No numeric phase runs, but the n_nz binning stays part of the
         # result so fused steady-state calls report the same telemetry
         # shape as cold calls (it's a cheap histogram, not a probe pass).
-        num_binning = bin_rows(nnz, upper=num_ladder.upper,
-                               num_bins=num_ladder.num_bins)
+        with phases.scope(phases.BIN):
+            num_binning = bin_rows(nnz, upper=num_ladder.upper,
+                                   num_bins=num_ladder.num_bins)
         return (C, total_nprod, total_nnz, sym_binning, num_binning,
-                sym_fall_prod)
+                sym_fall_prod, fall_nnz)
 
     return _finish_executable(plan, body)
 
@@ -597,6 +626,10 @@ class SpgemmEngine:
         self._hist_request = reg.histogram("opsparse_request_latency_seconds")
         self._hist_cold = reg.histogram("opsparse_cold_steps_seconds")
         self._hist_finalize = reg.histogram("opsparse_finalize_seconds")
+        # The hash epilogue's work and yield (see _note_epilogue).
+        self._epilogue_slots = reg.counter("opsparse_epilogue_slots_total")
+        self._epilogue_entries = reg.counter(
+            "opsparse_epilogue_entries_total")
         # Arena gauges/counters: snapshot-set from the (possibly shared)
         # arena's own accounting on every lease transition, so multiple
         # engines publishing into their own registries agree.
@@ -1085,8 +1118,8 @@ class SpgemmEngine:
         if not hot_eligible:
             state = plan.policy or PolicyState(
                 headroom=self.policy.headroom_init)
-            # StepTimer carries the tracer, so the six paper steps (setup,
-            # binnings, symbolic, alloc, numeric) emit kernel-phase spans
+            # StepTimer carries the tracer, so the six paper steps (nprod,
+            # the binnings, symbolic, rowptr, numeric) emit kernel-phase spans
             # nested under cold_steps — attribution on exactly the path
             # that already host-syncs per step.  Truly-cold calls keep the
             # timer on even untraced so benchmarks get the cold-phase
@@ -1328,12 +1361,13 @@ class SpgemmEngine:
         handles = (rec.handles[:-2] if rec.lease is not None
                    else rec.handles)   # the lease rides as the last pair
         if plan.config.method == "hash" and plan.config.fuse_numeric:
-            C, tnp, tnz, sym_binning, num_binning, sym_fall = handles
+            (C, tnp, tnz, sym_binning, num_binning, sym_fall,
+             fall_nnz) = handles
             # The ONE host sync: totals + sym bin sizes + fallback product
             # (num_binning is telemetry only — no numeric pass to verify).
             with self.telemetry.span("verify_sync", uid=rec.uid):
                 fetched = jax.device_get(
-                    (tnp, tnz, sym_binning.bin_size, sym_fall))
+                    (tnp, tnz, sym_binning.bin_size, sym_fall, fall_nnz))
             self._release_ws(rec)    # sync done: the workspace is idle
             total_nprod, total_nnz = int(fetched[0]), int(fetched[1])
             schedule_ok = plan.hash_schedule.admits_fused(
@@ -1346,14 +1380,19 @@ class SpgemmEngine:
                 return self._grow_and_redo(rec, total_nprod, total_nnz,
                                            schedule_overflow=not schedule_ok)
             self._note_hash_admit(rec, fetched[2], fetched[3])
+            self._note_epilogue(
+                spgemm_hash.epilogue_slots(
+                    plan.sym_ladder, plan.hash_schedule.sym_row_buckets,
+                    row_packing=plan.config.row_packing),
+                total_nnz - int(fetched[4]))
         elif plan.config.method == "hash":
             (C, tnp, tnz, sym_binning, num_binning,
-             sym_fall, num_fall) = handles
+             sym_fall, num_fall, fall_nnz) = handles
             # The ONE host sync: totals + bin sizes + fallback products.
             with self.telemetry.span("verify_sync", uid=rec.uid):
                 fetched = jax.device_get(
                     (tnp, tnz, sym_binning.bin_size, num_binning.bin_size,
-                     sym_fall, num_fall))
+                     sym_fall, num_fall, fall_nnz))
             self._release_ws(rec)    # sync done: the workspace is idle
             total_nprod, total_nnz = int(fetched[0]), int(fetched[1])
             schedule_ok = plan.hash_schedule.admits(
@@ -1367,6 +1406,10 @@ class SpgemmEngine:
                                            schedule_overflow=not schedule_ok)
             self._note_hash_admit(rec, fetched[2], fetched[4],
                                   num_sizes=fetched[3], num_fall=fetched[5])
+            self._note_epilogue(
+                spgemm_hash.epilogue_slots(
+                    plan.num_ladder, plan.hash_schedule.num_row_buckets),
+                total_nnz - int(fetched[6]))
         else:
             C, tnp, tnz, sym_binning, num_binning = handles
             with self.telemetry.span("verify_sync", uid=rec.uid):
@@ -1518,6 +1561,14 @@ class SpgemmEngine:
                     HashSchedule(*trimmed)).with_policy(state))
                 return
         self.cache.update_policy(entry, state)
+
+    def _note_epilogue(self, slots: int, entries: int) -> None:
+        """Count one admitted hot hash product's epilogue: the table slots
+        it sorted and scattered (padded rows and empty slots included)
+        and the entries of C it wrote.  Their ratio is the epilogue's
+        useful share of its work."""
+        self._epilogue_slots.inc(slots)
+        self._epilogue_entries.inc(entries)
 
     def _grow_and_redo(self, rec: _Pending, total_nprod: int,
                        total_nnz: int, *,

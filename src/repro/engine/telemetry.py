@@ -21,7 +21,19 @@ Spans
     splitting one request across sub-dispatches.  Synchronous nesting
     uses a thread-local stack (``with tel.span(...)``); the async
     dispatch->finalize split carries the open request span on the
-    engine's pending record and closes it at finalize.
+    engine's pending record and closes it at finalize.  While the handle
+    is enabled, each span is also a profiler annotation
+    ``opsparse.<name>`` (``jax.profiler.TraceAnnotation``), so a profiler
+    trace holds the engine's spans on the device trace's clock.
+
+Phases
+    ``repro/phases.py`` names the phases of a product once, under the
+    same ``opsparse.`` prefix as the spans: the traced executables wrap
+    each phase in a device scope, so every device op of a profiler trace
+    carries its phase in its ``op_name`` metadata.  The cold steps path
+    dispatches its phase functions one by one, where no surrounding scope
+    reaches the compiled ops; its ``StepTimer`` spans name it instead,
+    under the same names where a step is one phase.
 
 Metrics
     Counters, gauges, and histograms with fixed pow-2 latency buckets
@@ -30,33 +42,39 @@ Metrics
     one source of truth, not a parallel set of fields.
 
 Exporters
-    ``export_jsonl`` (one JSON object per line), ``export_chrome_trace``
-    (Chrome ``trace_event`` JSON loadable in Perfetto /
-    ``chrome://tracing``; spans become ``"X"`` complete events on a
-    per-request track), and :func:`prometheus_text` (Prometheus
-    exposition text for the future serving front-end).
+    ``export_jsonl`` (one JSON object per line) and
+    :func:`prometheus_text` (Prometheus exposition text, served by the
+    service's ``/metrics`` endpoint).
 
-This module deliberately imports neither JAX nor anything from the
-engine package, so stats/cache/executor can all depend on it freely.
+This module imports neither JAX (the annotation imports it when a span
+opens) nor anything from the engine package, so stats/cache/
+executor can all depend on it freely.
 """
 from __future__ import annotations
 
 import bisect
 import itertools
 import json
-import os
 import subprocess
 import threading
 import time
 from collections import deque
 from datetime import datetime, timezone
-from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Tuple, Union
+
+from repro.phases import PREFIX
 
 # Fixed pow-2 latency bucket edges, in seconds: 2^-14 s (~61 us) .. 2^6 s
 # (64 s).  Pow-2 edges mirror every other capacity in the engine — a
 # latency that moves one bucket is a real regime change, not jitter.
 LATENCY_BUCKETS_S: Tuple[float, ...] = tuple(2.0 ** e for e in range(-14, 7))
+
+
+def _annotation(name: str):
+    """An open profiler annotation ``opsparse.<name>``; a no-op unless a
+    profiler trace is being recorded when it opens."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(PREFIX + name)
 
 
 # ---------------------------------------------------------------------------
@@ -283,11 +301,12 @@ class Span:
     Usable as a context manager (pushes onto the telemetry's thread-local
     stack so inner spans nest under it) or held open across async
     boundaries and closed with :meth:`Telemetry.end_span` — the engine
-    keeps each request's span on its pending record until finalize.
+    keeps each request's span on its pending record until finalize.  Its
+    profiler annotation opens with it and closes at ``end_span``.
     """
 
     __slots__ = ("_tel", "name", "span_id", "parent_id", "uid", "t0", "t1",
-                 "attrs")
+                 "attrs", "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str, span_id: int,
                  parent_id: Optional[int], uid: Optional[int], t0: float,
@@ -300,6 +319,7 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self._annotation = _annotation(name)
 
     @property
     def dur(self) -> float:
@@ -455,6 +475,7 @@ class Telemetry:
         if span.t1 is not None:
             return
         span.t1 = time.perf_counter()
+        span._annotation.__exit__(None, None, None)
         if attrs:
             span.attrs.update(attrs)
         self.events.append(span)
@@ -480,47 +501,6 @@ class Telemetry:
                 f.write(json.dumps(item, default=str) + "\n")
         return len(items)
 
-    def chrome_trace(self) -> dict:
-        """Chrome ``trace_event`` payload (Perfetto / ``chrome://tracing``).
-
-        Spans become ``"X"`` complete events with microsecond timestamps
-        rebased to the earliest record; each request uid gets its own
-        ``tid`` track (engine-level spans ride track 0), so cold vs
-        steady requests and the sharded fan-out are visually separable.
-        Explicit ``span_id``/``parent_id`` ride in ``args``.
-        """
-        items = self.events.snapshot()
-        t_min = min((it.get("t0", it.get("t", 0.0)) for it in items),
-                    default=0.0)
-
-        def us(t):
-            return round((t - t_min) * 1e6, 3)
-
-        trace_events = []
-        for it in items:
-            tid = it.get("uid")
-            tid = 0 if tid is None else int(tid) + 1
-            if it.get("type") == "span":
-                trace_events.append({
-                    "name": it["name"], "ph": "X", "ts": us(it["t0"]),
-                    "dur": round(max(it["dur"], 0.0) * 1e6, 3),
-                    "pid": 1, "tid": tid,
-                    "args": {"span_id": it["span_id"],
-                             "parent_id": it["parent_id"],
-                             "uid": it["uid"], **it["attrs"]}})
-            else:
-                trace_events.append({
-                    "name": it["name"], "ph": "i", "ts": us(it["t"]),
-                    "s": "t", "pid": 1, "tid": tid,
-                    "args": {"uid": it.get("uid"), **it["attrs"]}})
-        return {"traceEvents": trace_events, "displayTimeUnit": "ms"}
-
-    def export_chrome_trace(self, path) -> dict:
-        payload = self.chrome_trace()
-        with open(path, "w") as f:
-            json.dump(payload, f, indent=1, default=str)
-        return payload
-
 
 def resolve_telemetry(arg: Union["Telemetry", bool, None]) -> "Telemetry":
     """Engine-constructor sugar: ``None``/``False`` -> a fresh disabled
@@ -535,60 +515,6 @@ def resolve_telemetry(arg: Union["Telemetry", bool, None]) -> "Telemetry":
 # the cache/partitioner when no engine telemetry was threaded through).
 # Never hand its registry to stats objects — it is process-global.
 NULL = Telemetry(enabled=False, events_capacity=1)
-
-
-# ---------------------------------------------------------------------------
-# Chrome trace_event schema validation (CI gate + tests).
-# ---------------------------------------------------------------------------
-
-_ALLOWED_PH = {"X", "B", "E", "i", "I", "M", "C"}
-
-
-def validate_chrome_trace(payload_or_path) -> int:
-    """Validate a Chrome ``trace_event`` payload; returns the event count.
-
-    Checks the JSON-object container shape, per-event required fields,
-    known phase types, non-negative ``dur`` on ``"X"`` complete events,
-    and matched ``B``/``E`` begin/end pairs per ``(pid, tid)`` track.
-    Raises :class:`ValueError` on the first violation.
-    """
-    payload = payload_or_path
-    if isinstance(payload, (str, Path)):
-        with open(payload) as f:
-            payload = json.load(f)
-    if not isinstance(payload, dict) or "traceEvents" not in payload:
-        raise ValueError("trace payload must be an object with 'traceEvents'")
-    events = payload["traceEvents"]
-    if not isinstance(events, list):
-        raise ValueError("'traceEvents' must be a list")
-    open_be: Dict[tuple, int] = {}
-    for i, ev in enumerate(events):
-        if not isinstance(ev, dict):
-            raise ValueError(f"event {i} is not an object")
-        for field in ("name", "ph", "ts", "pid", "tid"):
-            if field not in ev:
-                raise ValueError(f"event {i} missing '{field}'")
-        if not isinstance(ev["ts"], (int, float)):
-            raise ValueError(f"event {i} 'ts' is not numeric")
-        ph = ev["ph"]
-        if ph not in _ALLOWED_PH:
-            raise ValueError(f"event {i} has unknown phase {ph!r}")
-        track = (ev["pid"], ev["tid"])
-        if ph == "X":
-            if not isinstance(ev.get("dur"), (int, float)) or ev["dur"] < 0:
-                raise ValueError(f"event {i} ('X') needs numeric dur >= 0")
-        elif ph == "B":
-            open_be[track] = open_be.get(track, 0) + 1
-        elif ph == "E":
-            depth = open_be.get(track, 0)
-            if depth <= 0:
-                raise ValueError(f"event {i}: 'E' without matching 'B' "
-                                 f"on track {track}")
-            open_be[track] = depth - 1
-    unbalanced = {k: v for k, v in open_be.items() if v}
-    if unbalanced:
-        raise ValueError(f"unmatched 'B' events on tracks {unbalanced}")
-    return len(events)
 
 
 # ---------------------------------------------------------------------------

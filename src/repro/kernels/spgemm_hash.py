@@ -68,6 +68,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import phases
 from repro.core import esc
 from repro.core.analysis import exclusive_sum_in_place
 from repro.core.binning import Binning
@@ -495,19 +496,23 @@ def symbolic_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder,
     """
     _check_schedule(row_buckets, ladder, fallback_prod_capacity)
     m = A.nrows
-    nnz_buf = jnp.zeros(m + 1, dtype=jnp.int32)
+    with phases.scope(phases.ROWPTR):
+        nnz_buf = jnp.zeros(m + 1, dtype=jnp.int32)
     accesses = jnp.int32(0)
     sub_prod = jnp.int32(0)
 
     if row_buckets[-1]:
         # Global-memory-analog rung: ESC on the gathered sub-matrix.
-        rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
-        sub = gather_rows(A, rows, valid)
-        sub_prod = jnp.sum(
-            jnp.where(valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
-        sub_nnz = esc.symbolic(sub, B, prod_capacity=fallback_prod_capacity)
-        tgt = jnp.where(valid, rows, m + 1)
-        nnz_buf = nnz_buf.at[tgt].set(sub_nnz[:rows.shape[0]], mode="drop")
+        with phases.scope(phases.FALLBACK):
+            rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
+            sub = gather_rows(A, rows, valid)
+            sub_prod = jnp.sum(jnp.where(
+                valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
+            sub_nnz = esc.symbolic(sub, B,
+                                   prod_capacity=fallback_prod_capacity)
+            tgt = jnp.where(valid, rows, m + 1)
+            nnz_buf = nnz_buf.at[tgt].set(sub_nnz[:rows.shape[0]],
+                                          mode="drop")
 
     for b in range(len(ladder.table_sizes) - 1, -1, -1):
         rows_cap = row_buckets[b]
@@ -515,16 +520,17 @@ def symbolic_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder,
             continue
         pack = ladder.rows_per_block[b] if row_packing else 1
         pack = min(pack, rows_cap)         # both pow-2: stays divisible
-        rows, count = binning.rows_of_bin(b, rows_cap)
-        nnz_bin, acc_bin = symbolic_bin_call(
-            rows, count.reshape(1), A.rpt, A.col, B.rpt, B.col,
-            t_size=ladder.table_sizes[b], rows_cap=rows_cap, pack=pack,
-            single_access=single_access, interpret=interpret)
-        valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
-        tgt = jnp.where(valid, rows, m + 1)
-        nnz_buf = nnz_buf.at[tgt].set(nnz_bin, mode="drop")
-        if collect_accesses:
-            accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
+        with phases.scope(phases.hash_rung(b)):
+            rows, count = binning.rows_of_bin(b, rows_cap)
+            nnz_bin, acc_bin = symbolic_bin_call(
+                rows, count.reshape(1), A.rpt, A.col, B.rpt, B.col,
+                t_size=ladder.table_sizes[b], rows_cap=rows_cap, pack=pack,
+                single_access=single_access, interpret=interpret)
+            valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
+            tgt = jnp.where(valid, rows, m + 1)
+            nnz_buf = nnz_buf.at[tgt].set(nnz_bin, mode="drop")
+            if collect_accesses:
+                accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
 
     return nnz_buf, sub_prod, accesses
 
@@ -654,37 +660,43 @@ def numeric_scheduled(A: CSR, B: CSR, rpt: jax.Array, binning: Binning,
     """
     _check_schedule(row_buckets, ladder, fallback_prod_capacity)
     m, n = A.nrows, B.ncols
-    c_col = jnp.zeros(nnz_capacity, jnp.int32)
-    c_val = jnp.zeros(nnz_capacity, A.val.dtype)
+    with phases.scope(phases.ROWPTR):          # C's storage
+        c_col = jnp.zeros(nnz_capacity, jnp.int32)
+        c_val = jnp.zeros(nnz_capacity, A.val.dtype)
     accesses = jnp.int32(0)
     sub_prod = jnp.int32(0)
 
     if row_buckets[-1]:
-        rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
-        sub = gather_rows(A, rows, valid)
-        sub_prod = jnp.sum(
-            jnp.where(valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
-        subC = esc.spgemm_fused(sub, B,
-                                prod_capacity=fallback_prod_capacity,
-                                nnz_capacity=fallback_prod_capacity)
-        c_col, c_val = scatter_sub_rows(
-            subC, rows, valid, rpt, c_col, c_val, nnz_capacity=nnz_capacity)
+        with phases.scope(phases.FALLBACK):
+            rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
+            sub = gather_rows(A, rows, valid)
+            sub_prod = jnp.sum(jnp.where(
+                valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
+            subC = esc.spgemm_fused(sub, B,
+                                    prod_capacity=fallback_prod_capacity,
+                                    nnz_capacity=fallback_prod_capacity)
+        with phases.scope(phases.EPILOGUE_FALLBACK):
+            c_col, c_val = scatter_sub_rows(
+                subC, rows, valid, rpt, c_col, c_val,
+                nnz_capacity=nnz_capacity)
 
     for b in range(len(ladder.table_sizes) - 1, -1, -1):
         rows_cap = row_buckets[b]
         if not rows_cap:
             continue
-        rows, count = binning.rows_of_bin(b, rows_cap)
-        col_tabs, val_tabs, acc_bin = numeric_bin_call(
-            rows, count.reshape(1), A.rpt, A.col, A.val, B.rpt, B.col, B.val,
-            t_size=ladder.table_sizes[b], rows_cap=rows_cap,
-            single_access=single_access, interpret=interpret)
-        c_col, c_val = numeric_epilogue(
-            col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
-            nnz_capacity=nnz_capacity)
-        if collect_accesses:
-            valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
-            accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
+        with phases.scope(phases.hash_rung(b)):
+            rows, count = binning.rows_of_bin(b, rows_cap)
+            col_tabs, val_tabs, acc_bin = numeric_bin_call(
+                rows, count.reshape(1), A.rpt, A.col, A.val, B.rpt, B.col,
+                B.val, t_size=ladder.table_sizes[b], rows_cap=rows_cap,
+                single_access=single_access, interpret=interpret)
+            if collect_accesses:
+                valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
+                accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
+        with phases.scope(phases.epilogue_rung(b)):
+            c_col, c_val = numeric_epilogue(
+                col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
+                nnz_capacity=nnz_capacity)
 
     C = CSR(rpt=rpt, col=c_col, val=c_val, shape=(m, n))
     return C, sub_prod, accesses
@@ -745,7 +757,8 @@ def fused_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
     """
     _check_schedule(row_buckets, ladder, fallback_prod_capacity)
     m, n = A.nrows, B.ncols
-    nnz_buf = jnp.zeros(m + 1, dtype=jnp.int32)
+    with phases.scope(phases.ROWPTR):
+        nnz_buf = jnp.zeros(m + 1, dtype=jnp.int32)
     accesses = jnp.int32(0)
     sub_prod = jnp.int32(0)
     fallback = None
@@ -754,17 +767,18 @@ def fused_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
     if row_buckets[-1]:
         # Global-memory-analog rung, fused form: one ESC expansion yields
         # both the sub-result values AND (via its rpt) the per-row nnz.
-        rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
-        sub = gather_rows(A, rows, valid)
-        sub_prod = jnp.sum(
-            jnp.where(valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
-        subC = esc.spgemm_fused(sub, B,
-                                prod_capacity=fallback_prod_capacity,
-                                nnz_capacity=fallback_prod_capacity)
-        cap = rows.shape[0]
-        sub_nnz = (subC.rpt[1:cap + 1] - subC.rpt[:cap]).astype(jnp.int32)
-        tgt = jnp.where(valid, rows, m + 1)
-        nnz_buf = nnz_buf.at[tgt].set(sub_nnz, mode="drop")
+        with phases.scope(phases.FALLBACK):
+            rows, valid = _fallback_rows(binning, ladder, row_buckets[-1], m)
+            sub = gather_rows(A, rows, valid)
+            sub_prod = jnp.sum(jnp.where(
+                valid, nprod_of_rows(A, B, rows), 0)).astype(jnp.int32)
+            subC = esc.spgemm_fused(sub, B,
+                                    prod_capacity=fallback_prod_capacity,
+                                    nnz_capacity=fallback_prod_capacity)
+            cap = rows.shape[0]
+            sub_nnz = (subC.rpt[1:cap + 1] - subC.rpt[:cap]).astype(jnp.int32)
+            tgt = jnp.where(valid, rows, m + 1)
+            nnz_buf = nnz_buf.at[tgt].set(sub_nnz, mode="drop")
         fallback = (subC, rows, valid)
 
     for b in range(len(ladder.table_sizes) - 1, -1, -1):
@@ -773,33 +787,55 @@ def fused_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
             continue
         pack = ladder.rows_per_block[b] if row_packing else 1
         pack = min(pack, rows_cap)         # both pow-2: stays divisible
-        rows, count = binning.rows_of_bin(b, rows_cap)
-        nnz_bin, col_tabs, val_tabs, acc_bin = fused_bin_call(
-            rows, count.reshape(1), A.rpt, A.col, A.val, B.rpt, B.col, B.val,
-            t_size=ladder.table_sizes[b], rows_cap=rows_cap, pack=pack,
-            single_access=single_access, interpret=interpret)
-        valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
-        tgt = jnp.where(valid, rows, m + 1)
-        nnz_buf = nnz_buf.at[tgt].set(nnz_bin, mode="drop")
-        if collect_accesses:
-            accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
-        kept.append((rows, count, col_tabs, val_tabs))
+        with phases.scope(phases.hash_rung(b)):
+            rows, count = binning.rows_of_bin(b, rows_cap)
+            nnz_bin, col_tabs, val_tabs, acc_bin = fused_bin_call(
+                rows, count.reshape(1), A.rpt, A.col, A.val, B.rpt, B.col,
+                B.val, t_size=ladder.table_sizes[b], rows_cap=rows_cap,
+                pack=pack, single_access=single_access, interpret=interpret)
+            valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
+            tgt = jnp.where(valid, rows, m + 1)
+            nnz_buf = nnz_buf.at[tgt].set(nnz_bin, mode="drop")
+            if collect_accesses:
+                accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
+        kept.append((b, rows, count, col_tabs, val_tabs))
 
-    nnz = nnz_buf[:m]
-    rpt = exclusive_sum_in_place(nnz_buf)
-    c_col = jnp.zeros(nnz_capacity, jnp.int32)
-    c_val = jnp.zeros(nnz_capacity, A.val.dtype)
+    with phases.scope(phases.ROWPTR):
+        nnz = nnz_buf[:m]
+        rpt = exclusive_sum_in_place(nnz_buf)
+        c_col = jnp.zeros(nnz_capacity, jnp.int32)
+        c_val = jnp.zeros(nnz_capacity, A.val.dtype)
     if fallback is not None:
         subC, rows, valid = fallback
-        c_col, c_val = scatter_sub_rows(
-            subC, rows, valid, rpt, c_col, c_val, nnz_capacity=nnz_capacity)
-    for rows, count, col_tabs, val_tabs in kept:
-        c_col, c_val = numeric_epilogue(
-            col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
-            nnz_capacity=nnz_capacity)
+        with phases.scope(phases.EPILOGUE_FALLBACK):
+            c_col, c_val = scatter_sub_rows(
+                subC, rows, valid, rpt, c_col, c_val,
+                nnz_capacity=nnz_capacity)
+    for b, rows, count, col_tabs, val_tabs in kept:
+        with phases.scope(phases.epilogue_rung(b)):
+            c_col, c_val = numeric_epilogue(
+                col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
+                nnz_capacity=nnz_capacity)
 
     C = CSR(rpt=rpt, col=c_col, val=c_val, shape=(m, n))
     return C, nnz, sub_prod, accesses
+
+
+def epilogue_slots(ladder: BinLadder, row_buckets, *,
+                   row_packing: bool = False) -> int:
+    """Table slots the epilogue sorts and scatters for one product under a
+    schedule: over the populated table rungs, the rung's row bucket times
+    its dumped table stride (padding rows and empty slots included, as
+    :func:`numeric_epilogue` sorts whole tables).  ``row_packing`` as in
+    :func:`fused_scheduled`; the two-pass numeric tables are unpacked."""
+    slots = 0
+    for b, t_size in enumerate(ladder.table_sizes):
+        rows_cap = row_buckets[b]
+        if rows_cap:
+            pack = min(ladder.rows_per_block[b] if row_packing else 1,
+                       rows_cap)
+            slots += rows_cap * _step_geom(t_size, pack, rows_cap)[0]
+    return slots
 
 
 def fused_binned(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,

@@ -4,20 +4,25 @@ Nothing runs: the TPU compiler installed with JAX compiles for a chip
 that is described, not attached, and refuses what the chip would refuse
 (block shapes off the tiling, scalar loads from HBM, SMEM overflow).
 Interpret mode accepts all of these, so the CPU parity tests cannot see
-them.  The topology is described inside a fixture, never at import: only
+them.  The compiled text also shows the device scopes (``repro.phases``)
+each op of a steady executable carries.  The topology is described inside a fixture, never at import: only
 one process may load the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import phases
 from repro.core import CSR, SpgemmConfig
 from repro.core.binning_ranges import numeric_ladder, symbolic_ladder
-from repro.engine.executor import _build_hot_executable
-from repro.engine.plan import MatrixSig, plan as make_plan
+from repro.engine.executor import (_build_fused_hash_executable,
+                                   _build_hash_executable,
+                                   _build_hot_executable)
+from repro.engine.plan import HashSchedule, MatrixSig, plan as make_plan
 from repro.kernels import spgemm_hash
 
 # cage12 at the paper's full scale (benchmarks/matrices.py, scale=1).
@@ -114,3 +119,61 @@ def test_off_path_kernels_compile_for_v5e(one_chip):
     assert "tpu_custom_call" in spmm.lower(
         _arr(one_chip, nnzb), _arr(one_chip, nnzb), blocks,
         dense).compile().as_text()
+
+
+def _tiny_plan(method, fuse_numeric=True):
+    """A specialized plan at 256 rows whose schedule populates two table
+    rungs and the ESC fallback rung."""
+    sig = MatrixSig(nrows=256, ncols=256, cap_bucket=2048, dtype="float32")
+    plan = make_plan(sig, sig, SpgemmConfig(
+        method=method, fuse_numeric=fuse_numeric,
+        interpret=False)).with_capacities(1 << 14, 1 << 13)
+    if method != "hash":
+        return plan
+    sym = (32, 32) + (0,) * (plan.sym_ladder.num_bins - 3) + (16,)
+    num = (32, 32) + (0,) * (plan.num_ladder.num_bins - 3) + (16,)
+    return plan.with_hash_schedule(HashSchedule(sym, num, 1 << 12))
+
+
+@pytest.mark.parametrize("builder,method,fused,must", [
+    (_build_fused_hash_executable, "hash", True,
+     {"hash.r0", "hash.r1", "fallback", "epilogue.r0", "epilogue.r1",
+      "epilogue.fallback", "bin", "nprod"}),
+    (_build_hash_executable, "hash", False,
+     {"hash.r0", "hash.r1", "fallback", "epilogue.r0", "epilogue.r1",
+      "epilogue.fallback", "bin", "nprod"}),
+    (_build_hot_executable, "esc", True,
+     {"esc.sort", "esc.compress", "bin", "nprod"}),
+])
+def test_steady_executables_scope_their_device_ops(one_chip, builder,
+                                                   method, fused, must):
+    """Compiled for the chip, every sort, scatter and Pallas kernel of a
+    steady executable carries an ``opsparse.`` phase in its op_name, and
+    each phase that holds such ops shows."""
+    plan = _tiny_plan(method, fused)
+    spec = plan.workspace_spec()
+    sig = plan.a_sig
+    A = CSR(rpt=_arr(one_chip, sig.nrows + 1),
+            col=_arr(one_chip, sig.cap_bucket),
+            val=_arr(one_chip, sig.cap_bucket, jnp.float32),
+            shape=(sig.nrows, sig.ncols))
+    args = (A, A) + ((_arr(one_chip, spec.i32_cells),
+                      _arr(one_chip, spec.val_cells, jnp.float32))
+                     if spec is not None else ())
+    hlo = builder(plan).lower(*args).compile().as_text()
+    seen, bare = set(), []
+    for line in hlo.splitlines():
+        op = re.search(r" (sort|scatter|custom-call)\(", line)
+        if op is None or (op.group(1) == "custom-call"
+                          and "tpu_custom_call" not in line):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        scopes = [p[len(phases.PREFIX):]
+                  for p in (name.group(1) if name else "").split("/")
+                  if p.startswith(phases.PREFIX)]
+        if scopes:
+            seen.add(scopes[-1])
+        else:
+            bare.append(line.strip()[:120])
+    assert not bare, bare
+    assert must <= seen, must - seen

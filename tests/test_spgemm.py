@@ -103,8 +103,7 @@ def test_duplicate_accumulation_correctness():
 def test_timing_instrumentation():
     A, B = _pair(23)
     res = spgemm(A, B, SpgemmConfig(timing=True))
-    for step in ("setup", "symbolic_binning", "symbolic", "alloc",
-                 "numeric_binning", "numeric"):
+    for step in ("nprod", "bin", "symbolic", "rowptr", "numeric"):
         assert step in res.timings
 
 

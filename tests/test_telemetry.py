@@ -2,9 +2,8 @@
 
 Covers the observability acceptance surface: registry-backed stats
 (counter names, histogram bucket edges), span nesting under the sharded
-fan-out, ring-buffer overflow accounting, exporter schema validity
-(JSONL parses; Chrome trace_event validates), Prometheus exposition
-content, the disabled-mode no-op guarantee, and the empty-state
+fan-out, ring-buffer overflow accounting, the JSONL export, Prometheus
+exposition content, the disabled-mode no-op guarantee, and the empty-state
 edge cases of ``stats.render()`` and the exporters.
 """
 import json
@@ -16,7 +15,7 @@ from repro.core import SpgemmConfig, random_csr
 from repro.engine import (LATENCY_BUCKETS_S, EngineStats, EventLog,
                           MetricsRegistry, PlanStats, SpgemmEngine,
                           Telemetry, plan_label, prometheus_text, render,
-                          resolve_telemetry, validate_chrome_trace)
+                          resolve_telemetry)
 from repro.engine import stats as stats_mod
 from repro.engine.telemetry import (NULL_SPAN, Span, git_rev, utc_now_iso)
 
@@ -267,37 +266,6 @@ def test_jsonl_export_parses(traced_engine, tmp_path):
     assert len(lines) == n > 0
     rows = [json.loads(line) for line in lines]
     assert all(row["type"] in ("span", "event") for row in rows)
-
-
-def test_chrome_trace_export_validates(traced_engine, tmp_path):
-    path = tmp_path / "trace.json"
-    payload = traced_engine.telemetry.export_chrome_trace(path)
-    assert validate_chrome_trace(payload) == len(payload["traceEvents"])
-    assert validate_chrome_trace(path) > 0       # re-read from disk
-    # "X" complete events carry rebased non-negative microsecond stamps.
-    xs = [e for e in payload["traceEvents"] if e["ph"] == "X"]
-    assert xs and all(e["ts"] >= 0 and e["dur"] >= 0 for e in xs)
-    # Parentage rides in args so Perfetto queries can rebuild the tree.
-    assert all("span_id" in e["args"] for e in xs)
-
-
-def test_validate_chrome_trace_rejects_bad_payloads():
-    with pytest.raises(ValueError):
-        validate_chrome_trace([])                    # wrong container
-    with pytest.raises(ValueError):
-        validate_chrome_trace({"traceEvents": [{"ph": "X"}]})  # missing req
-    bad_dur = {"traceEvents": [
-        {"name": "a", "ph": "X", "ts": 0, "pid": 1, "tid": 1, "dur": -1}]}
-    with pytest.raises(ValueError):
-        validate_chrome_trace(bad_dur)
-    unmatched = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": 0, "pid": 1, "tid": 1}]}
-    with pytest.raises(ValueError):
-        validate_chrome_trace(unmatched)
-    matched = {"traceEvents": [
-        {"name": "a", "ph": "B", "ts": 0, "pid": 1, "tid": 1},
-        {"name": "a", "ph": "E", "ts": 1, "pid": 1, "tid": 1}]}
-    assert validate_chrome_trace(matched) == 2
 
 
 def test_prometheus_text_content(traced_engine):
