@@ -628,6 +628,8 @@ class SpgemmEngine:
         self._hist_finalize = reg.histogram("opsparse_finalize_seconds")
         # The hash epilogue's work and yield (see _note_epilogue).
         self._epilogue_slots = reg.counter("opsparse_epilogue_slots_total")
+        self._epilogue_gathered = reg.counter(
+            "opsparse_epilogue_gathered_slots_total")
         self._epilogue_entries = reg.counter(
             "opsparse_epilogue_entries_total")
         # Arena gauges/counters: snapshot-set from the (possibly shared)
@@ -1380,11 +1382,7 @@ class SpgemmEngine:
                 return self._grow_and_redo(rec, total_nprod, total_nnz,
                                            schedule_overflow=not schedule_ok)
             self._note_hash_admit(rec, fetched[2], fetched[3])
-            self._note_epilogue(
-                spgemm_hash.epilogue_slots(
-                    plan.sym_ladder, plan.hash_schedule.sym_row_buckets,
-                    row_packing=plan.config.row_packing),
-                total_nnz - int(fetched[4]))
+            self._note_epilogue(plan, total_nnz - int(fetched[4]))
         elif plan.config.method == "hash":
             (C, tnp, tnz, sym_binning, num_binning,
              sym_fall, num_fall, fall_nnz) = handles
@@ -1406,10 +1404,7 @@ class SpgemmEngine:
                                            schedule_overflow=not schedule_ok)
             self._note_hash_admit(rec, fetched[2], fetched[4],
                                   num_sizes=fetched[3], num_fall=fetched[5])
-            self._note_epilogue(
-                spgemm_hash.epilogue_slots(
-                    plan.num_ladder, plan.hash_schedule.num_row_buckets),
-                total_nnz - int(fetched[6]))
+            self._note_epilogue(plan, total_nnz - int(fetched[6]))
         else:
             C, tnp, tnz, sym_binning, num_binning = handles
             with self.telemetry.span("verify_sync", uid=rec.uid):
@@ -1562,12 +1557,25 @@ class SpgemmEngine:
                 return
         self.cache.update_policy(entry, state)
 
-    def _note_epilogue(self, slots: int, entries: int) -> None:
-        """Count one admitted hot hash product's epilogue: the table slots
-        it sorted and scattered (padded rows and empty slots included)
-        and the entries of C it wrote.  Their ratio is the epilogue's
-        useful share of its work."""
-        self._epilogue_slots.inc(slots)
+    def _note_epilogue(self, plan: SpgemmPlan, entries: int) -> None:
+        """Count one admitted hot hash product's epilogue, from its static
+        schedule: the table slots it sorted (padded rows and empty slots
+        included), those of them in rungs that gathered C's entries
+        rather than scattering every slot, and the entries of C it wrote.
+        Entries over slots is the epilogue's useful share of its work;
+        gathered over all slots, how much of it took the gather path."""
+        sched = plan.hash_schedule
+        if plan.config.fuse_numeric:       # fused: the symbolic ladder
+            ladder, buckets = plan.sym_ladder, sched.sym_row_buckets
+            packing = plan.config.row_packing
+        else:                              # two-pass numeric: unpacked
+            ladder, buckets, packing = (plan.num_ladder,
+                                        sched.num_row_buckets, False)
+        self._epilogue_slots.inc(spgemm_hash.epilogue_slots(
+            ladder, buckets, row_packing=packing))
+        self._epilogue_gathered.inc(spgemm_hash.epilogue_gathered_slots(
+            ladder, buckets, nnz_capacity=plan.nnz_bucket,
+            row_packing=packing))
         self._epilogue_entries.inc(entries)
 
     def _grow_and_redo(self, rec: _Pending, total_nprod: int,

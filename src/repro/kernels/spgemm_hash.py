@@ -34,10 +34,12 @@ table optimistically).  A probe-count guard (2*t_size) still protects
 against misuse.
 
 Sorting/condensing (paper's numeric "condense + sort" phases): done as a
-*vectorized epilogue* outside the kernel — per-row argsort over the dumped
-tables.  On TPU, sorts vectorize on the VPU, whereas in-kernel scalar
-condense loops would serialize; this is the hardware adaptation recorded in
-DESIGN.md.
+*vectorized epilogue* outside the kernel — a per-row sort of the dumped
+tables with the values as payload, then C's entries either gathered out
+of the sorted tables (a rung whose tables outnumber C's positions) or
+the tables' slots scattered into C (a smaller rung).  On TPU, sorts
+vectorize on the VPU, whereas in-kernel scalar condense loops would
+serialize; this is the hardware adaptation recorded in DESIGN.md.
 
 Fusion (paper opt. 2, taken one step further): the two-pass flow builds
 every row's hash table TWICE — the symbolic phase counts it, the numeric
@@ -416,35 +418,111 @@ def fused_bin_call(rows, count, a_rpt, a_col, a_val, b_rpt, b_col, b_val,
 
 # ---------------------------------------------------------------------------
 # Vectorized epilogue: condense + sort the dumped tables into CSR storage.
+#
+# Each rung's tables are sorted row by row (empties keyed to INT32_MAX sort
+# last, the values ride along as the sort's payload).  The sorted entries
+# then reach C one of two ways, picked per rung from static shapes
+# (:func:`epilogue_gathers`): a rung whose table holds more slots than C
+# has positions PULLS C's entries out of the sorted table (a gather over
+# C's positions, each finding its slot through a running sum over C's
+# row pointers); a smaller rung PUSHES its slots into C (a masked
+# scatter over the table).  No arithmetic differs, so both give the same
+# C bit for bit.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("nnz_capacity",))
-def numeric_epilogue(col_tabs, val_tabs, bin_rows, count, rpt, c_col, c_val,
-                     *, nnz_capacity: int):
-    """Sort each row's table by column id and scatter into C storage.
+def epilogue_gathers(rows_cap: int, stride: int, nnz_capacity: int) -> bool:
+    """Whether a rung's epilogue gathers C's entries from its sorted table
+    (its ``rows_cap x stride`` table outnumbers C's ``nnz_capacity``
+    positions) rather than scattering every table slot into C."""
+    return rows_cap * stride > nnz_capacity
 
-    The paper's condense (shared_offset atomics) + sort phases, vectorized:
-    argsort over the table (empties keyed to INT32_MAX sort last), masked
-    scatter to ``C.col/C.val`` at ``rpt[row] + j``.
-    """
-    rows_cap, t_pad = col_tabs.shape
-    sort_key = jnp.where(col_tabs < 0, INT32_MAX, col_tabs)
-    order = jnp.argsort(sort_key, axis=1)
-    col_sorted = jnp.take_along_axis(col_tabs, order, axis=1)
-    val_sorted = jnp.take_along_axis(val_tabs, order, axis=1)
-    nnz_row = jnp.sum((col_tabs >= 0).astype(jnp.int32), axis=1)
 
+def _sort_tables(col_tabs, val_tabs):
+    """Each row's table sorted by column, empties (keyed to INT32_MAX)
+    last.  Returns ``(key, val)``: a row's first ``nnz`` keys are its
+    columns, ascending."""
+    key = jnp.where(col_tabs < 0, INT32_MAX, col_tabs)
+    return jax.lax.sort((key, val_tabs), dimension=1, num_keys=1)
+
+
+def _per_position(rpt, per_row, capacity: int):
+    """``per_row``'s value of the row of each position of C's storage,
+    ``(capacity,)`` int32.
+
+    Each row's value lands at its ``rpt`` as the difference from the
+    row before (empty rows' differences add up into the next row that
+    starts at the same position), and a running sum carries it over the
+    row's positions.  Positions past nnz(C) read the value of the last
+    row that starts at or before them: callers mask them off."""
+    steps = jnp.zeros(capacity, jnp.int32).at[rpt[:-1]].add(
+        jnp.diff(per_row, prepend=0), mode="drop")
+    return jnp.cumsum(steps)
+
+
+@functools.partial(jax.jit, donate_argnames=("c_col", "c_val"))
+def epilogue_scatter(col_tabs, val_tabs, bin_rows, count, rpt, c_col, c_val):
+    """Sort each row's table and scatter every slot into C storage: slot
+    ``j`` of row ``r`` to ``rpt[r] + j``, slots past the row's nnz (and
+    padding rows) out of bounds, where they drop.  C's storage is
+    donated: called outside a jit, C is updated in place."""
+    rows_cap, stride = col_tabs.shape
+    key, val = _sort_tables(col_tabs, val_tabs)
+    nnz_row = jnp.sum((key < INT32_MAX).astype(jnp.int32), axis=1)
     valid_row = jnp.arange(rows_cap, dtype=jnp.int32) < count
-    lane = jnp.arange(t_pad, dtype=jnp.int32)[None, :]
-    in_row = lane < nnz_row[:, None]
-    mask = in_row & valid_row[:, None]
+    lane = jnp.arange(stride, dtype=jnp.int32)[None, :]
+    mask = (lane < nnz_row[:, None]) & valid_row[:, None]
     start = rpt[jnp.where(valid_row, bin_rows, 0)][:, None]
-    target = jnp.where(mask, start + lane, nnz_capacity)   # OOB -> dropped
-    c_col = c_col.at[target.reshape(-1)].set(
-        col_sorted.reshape(-1), mode="drop")
-    c_val = c_val.at[target.reshape(-1)].set(
-        val_sorted.reshape(-1), mode="drop")
+    target = jnp.where(mask, start + lane, c_col.shape[0]).reshape(-1)
+    c_col = c_col.at[target].set(key.reshape(-1), mode="drop")
+    c_val = c_val.at[target].set(val.reshape(-1), mode="drop")
     return c_col, c_val
+
+
+@functools.partial(jax.jit, donate_argnames=("c_col", "c_val"))
+def epilogue_gather(col_tabs, val_tabs, bin_rows, count, rpt, c_col, c_val):
+    """Sort each row's table and gather C's entries of this rung's rows
+    out of it: position ``k`` of row ``r`` takes slot ``k - rpt[r]`` of
+    ``r``'s table.  Positions of other rows and past nnz(C) keep what
+    they hold.  ``bin_rows`` lists the valid rows in increasing order, as
+    ``Binning.rows_of_bin`` does.  C's storage is donated, as in
+    :func:`epilogue_scatter`."""
+    rows_cap, stride = col_tabs.shape
+    cap, m = c_col.shape[0], rpt.shape[0] - 1
+    key, val = _sort_tables(col_tabs, val_tabs)
+    valid_row = jnp.arange(rows_cap, dtype=jnp.int32) < count
+    slot = jnp.full(m, -1, jnp.int32).at[
+        jnp.where(valid_row, bin_rows, m)].set(
+            jnp.arange(rows_cap, dtype=jnp.int32), mode="drop")
+    # A row's flat table index of its entry k, less k; below -k off
+    # the rung.
+    base = jnp.where(slot >= 0, slot * stride - rpt[:m], -cap)
+    k = jnp.arange(cap, dtype=jnp.int32)
+    src = k + _per_position(rpt, base, cap)
+    take = (src >= 0) & (k < rpt[m])
+    # ``bin_rows`` lists a rung's rows in row order, so their slots, and
+    # the sources of C's positions, rise with k; the running max carries
+    # them over the other positions, which makes the gather's indices
+    # sorted.  Keys and values ride one gather, a pair per index.
+    src = jax.lax.cummax(jnp.where(take, src, 0))
+    key, val = key.reshape(-1), val.reshape(-1)
+    if val.dtype.itemsize == key.dtype.itemsize:
+        pair = jnp.stack([key, jax.lax.bitcast_convert_type(val, key.dtype)])
+        got = pair.at[:, src].get(indices_are_sorted=True,
+                                  mode="promise_in_bounds")
+        key_k, val_k = got[0], jax.lax.bitcast_convert_type(got[1], val.dtype)
+    else:
+        key_k, val_k = key[src], val[src]
+    return jnp.where(take, key_k, c_col), jnp.where(take, val_k, c_val)
+
+
+def numeric_epilogue(rung: int, col_tabs, val_tabs, bin_rows, count, rpt,
+                     c_col, c_val):
+    """Rung ``rung``'s dumped tables into C under the rung's epilogue
+    scope, by gather or by scatter as :func:`epilogue_gathers` rules."""
+    gathers = epilogue_gathers(*col_tabs.shape, c_col.shape[0])
+    with phases.scope(phases.epilogue_rung(rung)):
+        return (epilogue_gather if gathers else epilogue_scatter)(
+            col_tabs, val_tabs, bin_rows, count, rpt, c_col, c_val)
 
 
 # ---------------------------------------------------------------------------
@@ -693,10 +771,8 @@ def numeric_scheduled(A: CSR, B: CSR, rpt: jax.Array, binning: Binning,
             if collect_accesses:
                 valid = jnp.arange(rows_cap, dtype=jnp.int32) < count
                 accesses = accesses + jnp.sum(jnp.where(valid, acc_bin, 0))
-        with phases.scope(phases.epilogue_rung(b)):
-            c_col, c_val = numeric_epilogue(
-                col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
-                nnz_capacity=nnz_capacity)
+        c_col, c_val = numeric_epilogue(b, col_tabs, val_tabs, rows, count,
+                                        rpt, c_col, c_val)
 
     C = CSR(rpt=rpt, col=c_col, val=c_val, shape=(m, n))
     return C, sub_prod, accesses
@@ -738,7 +814,7 @@ def fused_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
     val) tables, the fallback rung runs the single-expansion ESC
     (``esc.spgemm_fused``, its n_nz read off the sub-result's rpt), and
     once every row's nnz is known the row pointers are an exclusive sum
-    and the dumped tables condense/sort/scatter into C — no second probe
+    and the dumped tables condense/sort into C — no second probe
     pass anywhere.  The symbolic-ladder tables are sized by n_prod
     (>= n_nz), so the numeric accumulation can never overflow them; the
     larger tables trade VMEM footprint for a LOWER collision rate than
@@ -812,30 +888,44 @@ def fused_scheduled(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
                 subC, rows, valid, rpt, c_col, c_val,
                 nnz_capacity=nnz_capacity)
     for b, rows, count, col_tabs, val_tabs in kept:
-        with phases.scope(phases.epilogue_rung(b)):
-            c_col, c_val = numeric_epilogue(
-                col_tabs, val_tabs, rows, count, rpt, c_col, c_val,
-                nnz_capacity=nnz_capacity)
+        c_col, c_val = numeric_epilogue(b, col_tabs, val_tabs, rows, count,
+                                        rpt, c_col, c_val)
 
     C = CSR(rpt=rpt, col=c_col, val=c_val, shape=(m, n))
     return C, nnz, sub_prod, accesses
 
 
-def epilogue_slots(ladder: BinLadder, row_buckets, *,
-                   row_packing: bool = False) -> int:
-    """Table slots the epilogue sorts and scatters for one product under a
-    schedule: over the populated table rungs, the rung's row bucket times
-    its dumped table stride (padding rows and empty slots included, as
-    :func:`numeric_epilogue` sorts whole tables).  ``row_packing`` as in
-    :func:`fused_scheduled`; the two-pass numeric tables are unpacked."""
-    slots = 0
+def _dumped_tables(ladder: BinLadder, row_buckets, row_packing: bool):
+    """``(rows_cap, stride)`` of each populated table rung's dumped
+    tables under a schedule (``row_packing`` as in
+    :func:`fused_scheduled`; the two-pass numeric tables are unpacked)."""
     for b, t_size in enumerate(ladder.table_sizes):
         rows_cap = row_buckets[b]
         if rows_cap:
             pack = min(ladder.rows_per_block[b] if row_packing else 1,
                        rows_cap)
-            slots += rows_cap * _step_geom(t_size, pack, rows_cap)[0]
-    return slots
+            yield rows_cap, _step_geom(t_size, pack, rows_cap)[0]
+
+
+def epilogue_slots(ladder: BinLadder, row_buckets, *,
+                   row_packing: bool = False) -> int:
+    """Table slots the epilogue sorts for one product under a schedule:
+    over the populated table rungs, the rung's row bucket times its
+    dumped table stride (padding rows and empty slots included, as
+    :func:`numeric_epilogue` sorts whole tables)."""
+    return sum(r * s for r, s in _dumped_tables(ladder, row_buckets,
+                                                row_packing))
+
+
+def epilogue_gathered_slots(ladder: BinLadder, row_buckets, *,
+                            nnz_capacity: int,
+                            row_packing: bool = False) -> int:
+    """The part of :func:`epilogue_slots` in rungs whose epilogue gathers
+    C's entries (:func:`epilogue_gathers`) instead of scattering every
+    slot."""
+    return sum(r * s for r, s in _dumped_tables(ladder, row_buckets,
+                                                row_packing)
+               if epilogue_gathers(r, s, nnz_capacity))
 
 
 def fused_binned(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,

@@ -32,8 +32,8 @@ def hash_rung(rung: int) -> str:
 
 
 def epilogue_rung(rung: int) -> str:
-    """Rung ``rung``'s epilogue: the sort and scatter of its dumped tables
-    into C (``numeric_epilogue``)."""
+    """Rung ``rung``'s epilogue: the sort of its dumped tables and their
+    entries' way into C, by gather or by scatter (``numeric_epilogue``)."""
     return f"epilogue.r{rung}"
 
 

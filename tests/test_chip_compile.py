@@ -13,6 +13,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -177,3 +178,46 @@ def test_steady_executables_scope_their_device_ops(one_chip, builder,
             bare.append(line.strip()[:120])
     assert not bare, bare
     assert must <= seen, must - seen
+
+
+def _scatter_updates(hlo, scope):
+    """Update counts of the scatters whose innermost ``opsparse.`` scope
+    is ``scope``, read off the shapes of their last operand."""
+    shapes = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    counts = []
+    for line in hlo.splitlines():
+        op = re.search(r" scatter\(([^)]*)\)", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op is None or name is None:
+            continue
+        inner = [p for p in name.group(1).split("/")
+                 if p.startswith(phases.PREFIX)]
+        if inner and inner[-1] == phases.PREFIX + scope:
+            dims = shapes[op.group(1).split(",")[-1].strip()]
+            counts.append(int(np.prod([int(d) for d in dims.split(",")
+                                       if d])))
+    return counts
+
+
+def test_fused_hash_gather_rung_compiles_for_v5e(one_chip):
+    """The steady fused hash executable compiles for the chip at a
+    schedule where rung 1's tables (32 rows x 512 slots) outnumber C's
+    8192 positions, so its epilogue gathers: no scatter of its scope
+    moves a slot per table entry, while rung 0 (32 x 128) still scatters
+    every slot."""
+    plan = _tiny_plan("hash")
+    sig = plan.a_sig
+    spec = plan.workspace_spec()
+    A = CSR(rpt=_arr(one_chip, sig.nrows + 1),
+            col=_arr(one_chip, sig.cap_bucket),
+            val=_arr(one_chip, sig.cap_bucket, jnp.float32),
+            shape=(sig.nrows, sig.ncols))
+    args = (A, A, _arr(one_chip, spec.i32_cells),
+            _arr(one_chip, spec.val_cells, jnp.float32))
+    hlo = _build_fused_hash_executable(plan).lower(*args).compile().as_text()
+    assert spgemm_hash.epilogue_gathers(32, 512, plan.nnz_bucket)
+    assert not spgemm_hash.epilogue_gathers(32, 128, plan.nnz_bucket)
+    gathering = _scatter_updates(hlo, "epilogue.r1")
+    assert gathering and max(gathering) <= sig.nrows   # per row, not slot
+    assert _scatter_updates(hlo, "epilogue.r0").count(32 * 128) == 2
+    assert re.search(r" gather\(.*opsparse\.epilogue\.r1/", hlo)

@@ -348,3 +348,151 @@ def test_interpret_auto_detect():
     assert default_interpret() == (jax.default_backend() != "tpu")
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
+
+
+# ---------------------------------------------------------------------------
+# The epilogue's two ways into C: gather from the sorted tables, or scatter
+# every table slot.  Same arithmetic, so the same C bit for bit.
+# ---------------------------------------------------------------------------
+
+def _epilogue_operands(seed=13, m=96, k=64, n=64):
+    """A product whose A has empty rows (every 7th), with its structural
+    row sizes and C's row pointers."""
+    from repro.core import CSR
+    A, B = _pair(seed, m, k, n, 2.0, 3.0)
+    dense = np.asarray(A.to_dense()).copy()
+    dense[::7] = 0.0
+    A = CSR.from_dense(dense)
+    a, b = (dense != 0).astype(np.int64), (np.asarray(B.to_dense()) != 0)
+    support = (a @ b.astype(np.int64)) > 0
+    nprod = a @ b.sum(axis=1)
+    rpt = np.concatenate([[0], np.cumsum(support.sum(axis=1))])
+    return A, B, support, nprod, rpt.astype(np.int32)
+
+
+@pytest.mark.parametrize(
+    "kind,t_size,pack,rows_cap,gathers,fallback,dtype", [
+        ("fused", 32, 1, 64, True, False, "float32"),    # symbolic, unpacked
+        ("fused", 32, 1, 64, False, False, "float32"),   # rule's boundary
+        ("fused", 32, 32, 64, True, False, "float32"),   # packed sub-tables
+        ("fused", 32, 1, 64, True, True, "float32"),     # a fallback rung
+        ("numeric", 31, 1, 64, True, False, "float32"),  # mod probing
+        ("numeric", 255, 1, 16, False, False, "float32"),
+        ("fused", 32, 1, 64, True, False, "bfloat16"),   # values not 32-bit
+    ])
+def test_epilogue_gather_matches_scatter_bitwise(kind, t_size, pack,
+                                                 rows_cap, gathers,
+                                                 fallback, dtype):
+    """Gathering C's entries from a rung's sorted tables writes exactly
+    what scattering every slot writes, and nothing else: positions of
+    rows off the rung (a fallback rung's among them) and past nnz(C)
+    keep what they held.  The rung holds empty rows, C's last row, fewer
+    valid rows than its bucket, and a padding row id of a row off the
+    rung."""
+    from repro.core.csr import gather_rows
+    A, B, support, nprod, rpt = _epilogue_operands()
+    m = A.nrows
+    fits = nprod <= t_size * 0.8 if kind == "fused" else \
+        support.sum(axis=1) <= t_size // 2
+    on = [r for r in range(m) if fits[r] and (r % 2 or r % 7 == 0)]
+    count = min(len(on), rows_cap - 3)
+    on = on[-count:]                 # C's last row among them
+    off = [r for r in range(m) if r not in on and support[r].any()]
+    assert any(support[r].sum() == 0 for r in on) and count < rows_cap
+    assert on[-1] == m - 1 and support[m - 1].any()
+    rows = np.full(rows_cap, off[0], np.int32)        # padding: a real row
+    rows[:count] = on
+    rows, cnt = jnp.asarray(rows), jnp.asarray([count], jnp.int32)
+    if kind == "fused":
+        _, col_tabs, val_tabs, _ = spgemm_hash.fused_bin_call(
+            rows, cnt, A.rpt, A.col, A.val, B.rpt, B.col, B.val,
+            t_size=t_size, rows_cap=rows_cap, pack=pack)
+    else:
+        col_tabs, val_tabs, _ = spgemm_hash.numeric_bin_call(
+            rows, cnt, A.rpt, A.col, A.val, B.rpt, B.col, B.val,
+            t_size=t_size, rows_cap=rows_cap, single_access=True)
+    val_tabs = val_tabs.astype(dtype)
+    cap = next_bucket(int(rpt[-1])) if gathers else col_tabs.size
+    assert spgemm_hash.epilogue_gathers(*col_tabs.shape, cap) == gathers
+    assert rpt[-1] <= cap
+    rpt_d = jnp.asarray(rpt)
+    c_col = -2 - jnp.arange(cap, dtype=jnp.int32)      # "held before"
+    c_val = (1000.0 + jnp.arange(cap, dtype=jnp.float32)).astype(dtype)
+    if fallback:                                       # a real ESC rung
+        f_rows = jnp.asarray(off[:3] + [m] * 5, jnp.int32)
+        valid = jnp.arange(8) < 3
+        subC = esc.spgemm_fused(gather_rows(A, f_rows, valid), B,
+                                prod_capacity=1024, nnz_capacity=1024)
+        c_col, c_val = spgemm_hash.scatter_sub_rows(
+            subC, f_rows, valid, rpt_d, c_col, c_val, nnz_capacity=cap)
+    before = np.asarray(c_col), np.asarray(c_val)
+    g_col, g_val = spgemm_hash.epilogue_gather(     # C's storage donated
+        col_tabs, val_tabs, rows, cnt[0], rpt_d, jnp.array(before[0]),
+        jnp.array(before[1]))
+    s_col, s_val = spgemm_hash.epilogue_scatter(
+        col_tabs, val_tabs, rows, cnt[0], rpt_d, jnp.array(before[0]),
+        jnp.array(before[1]))
+    np.testing.assert_array_equal(np.asarray(g_col), np.asarray(s_col))
+    np.testing.assert_array_equal(
+        np.asarray(g_val.view(jnp.int16 if dtype == "bfloat16" else jnp.int32)),
+        np.asarray(s_val.view(jnp.int16 if dtype == "bfloat16" else jnp.int32)))
+    mine = np.zeros(cap, bool)
+    for r in on:
+        mine[rpt[r]:rpt[r + 1]] = True
+        np.testing.assert_array_equal(np.asarray(g_col)[rpt[r]:rpt[r + 1]],
+                                      np.flatnonzero(support[r]))
+    np.testing.assert_array_equal(np.asarray(g_col)[~mine], before[0][~mine])
+    np.testing.assert_array_equal(np.asarray(g_val)[~mine], before[1][~mine])
+    ref = np.asarray(A.to_dense()) @ np.asarray(B.to_dense())
+    got = np.asarray(g_val.astype(jnp.float32))
+    tol = 1e-2 if dtype == "bfloat16" else 1e-5
+    for r in on:
+        np.testing.assert_allclose(got[rpt[r]:rpt[r + 1]],
+                                   ref[r, support[r]], rtol=tol, atol=tol)
+
+
+def test_per_position_carries_each_rows_value():
+    """Each position of C's storage reads its row's value; empty rows
+    own no position, and positions past nnz(C) read the last row that
+    starts at or before them."""
+    rpt = jnp.asarray([0, 0, 3, 3, 3, 5, 6, 6], jnp.int32)   # 7 rows
+    per_row = jnp.asarray([-9, 40, 7, -2, 11, 5, 3], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(spgemm_hash._per_position(rpt, per_row, 8)),
+        [40, 40, 40, 11, 11, 5, 3, 3])
+
+
+@pytest.mark.parametrize("m,k,n,da,db,short_rows", [
+    (48, 40, 40, 4.0, 4.0, 0),      # both populated rungs gather
+    (16, 64, 600, 40.0, 30.0, 3),   # long rows gather, short rows scatter
+])
+def test_engine_hash_gather_epilogue_matches_esc(m, k, n, da, db,
+                                                 short_rows):
+    """Through the engine, a hash product whose epilogue gathers equals
+    the ESC product exactly: integer values keep every sum exact, so the
+    summation order cannot tell the two methods apart."""
+    from repro.core import CSR
+    A, B = _pair(21, m, k, n, da, db)
+    rng = np.random.RandomState(4)
+    dA, dB = (np.where(d != 0, rng.randint(1, 5, d.shape), 0)
+              .astype(np.float32)
+              for d in (np.asarray(A.to_dense()), np.asarray(B.to_dense())))
+    dA[:short_rows, 1:] = 0.0       # rows of a small rung, which scatters
+    A, B = CSR.from_dense(dA), CSR.from_dense(dB)
+    hash_engine = SpgemmEngine(SpgemmConfig(method="hash"))
+    got = [hash_engine.execute(A, B) for _ in range(2)][-1]
+    plan = next(e for _, e in hash_engine.cache.items()).plan
+    buckets = plan.hash_schedule.sym_row_buckets
+    gathered = spgemm_hash.epilogue_gathered_slots(
+        plan.sym_ladder, buckets, nnz_capacity=plan.nnz_bucket)
+    slots = spgemm_hash.epilogue_slots(plan.sym_ladder, buckets)
+    assert 0 < gathered and (gathered < slots) == bool(short_rows)
+    want = SpgemmEngine(SpgemmConfig(method="esc")).execute(A, B)
+    nnz = want.total_nnz
+    assert got.total_nnz == nnz > 0
+    np.testing.assert_array_equal(np.asarray(got.C.rpt),
+                                  np.asarray(want.C.rpt))
+    np.testing.assert_array_equal(np.asarray(got.C.col)[:nnz],
+                                  np.asarray(want.C.col)[:nnz])
+    np.testing.assert_array_equal(np.asarray(got.C.val)[:nnz],
+                                  np.asarray(want.C.val)[:nnz])

@@ -3,9 +3,9 @@
 A CPU profiler trace of ``SpgemmService.call`` holds the engine's spans
 as ``opsparse.<name>`` annotations, nested as the engine opened them,
 and a disabled ``Telemetry`` adds none.  The hot hash finalize counts
-the epilogue's table slots and the entries it writes.  (The device
-scopes of the steady executables are checked where they compile for
-the chip, ``tests/test_chip_compile.py``.)
+the epilogue's table slots, those of its gathering rungs, and the
+entries it writes.  (The device scopes of the steady executables are
+checked where they compile for the chip, ``tests/test_chip_compile.py``.)
 """
 import glob
 import os
@@ -122,6 +122,35 @@ def test_epilogue_counters_count_slots_and_entries():
     text = prometheus_text(engine)
     assert f"opsparse_epilogue_slots_total {hot * slots}" in text
     assert "opsparse_epilogue_entries_total " in text
+
+
+@pytest.mark.parametrize("nnz_bucket", [None, 1 << 16])
+def test_epilogue_gathered_slots_counter(nnz_bucket):
+    """Each admitted hot hash product adds the slots of its rungs whose
+    epilogue gathers (their tables outnumber C's positions); with C's
+    storage prewarmed past every rung's table, none gathers and the
+    counter reads 0.  Prometheus exposes it under its own name."""
+    A, B = _pair(5, m=48, k=40, n=40, avg=4.0)
+    engine = SpgemmEngine(SpgemmConfig(method="hash"))
+    if nnz_bucket:
+        engine.prewarm(A, B, prod_bucket=1 << 14, nnz_bucket=nnz_bucket)
+    for _ in range(3):
+        engine.execute(A, B)
+    entry = next(e for _, e in engine.cache.items())
+    plan, hot = entry.plan, entry.stats.hot_calls
+    assert hot == 2
+    gathered = spgemm_hash.epilogue_gathered_slots(
+        plan.sym_ladder, plan.hash_schedule.sym_row_buckets,
+        nnz_capacity=plan.nnz_bucket)
+    slots = spgemm_hash.epilogue_slots(
+        plan.sym_ladder, plan.hash_schedule.sym_row_buckets)
+    assert (0 < gathered <= slots) if nnz_bucket is None else gathered == 0
+    reg = engine.telemetry.registry
+    assert reg.get("opsparse_epilogue_gathered_slots_total").value \
+        == hot * gathered
+    assert reg.get("opsparse_epilogue_slots_total").value == hot * slots
+    assert (f"opsparse_epilogue_gathered_slots_total {hot * gathered}"
+            in prometheus_text(engine))
 
 
 def test_epilogue_slots_are_the_dumped_tables():
