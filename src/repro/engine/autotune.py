@@ -348,6 +348,14 @@ def trim_buckets(maxima: Tuple[int, ...], current: Tuple[int, ...],
         for b, (s, cur) in enumerate(zip(maxima, current)))
 
 
+def fallback_need(sym_fall: int, num_fall: int, fused: bool) -> int:
+    """The fallback expansion capacity a hash plan's steady executable
+    needs: a fused plan expands only the symbolic ladder's fallback rung;
+    a two-pass plan expands both phases' with one shared capacity, so it
+    needs the larger."""
+    return sym_fall if fused else max(sym_fall, num_fall)
+
+
 def trim_fallback(fall_max: int, current: int, headroom: float,
                   active: bool) -> int:
     """Trimmed fallback-expansion capacity.
@@ -376,11 +384,10 @@ def trim_schedule(state: PolicyState, current, *, m: int,
     ``HashSchedule`` — the caller owns the dataclass to keep this module
     import-light (plan.py imports us for ``PolicyState``).  Fused plans
     observe (and trim) only the symbolic side — there is no numeric
-    probe pass — so their numeric buckets ride along unchanged, and the
-    shared fallback capacity is sized to the max of both phases'
-    observed sub-products (the state keeps them separate so policy
-    serialization and ``note_admit`` call sites are unchanged; they
-    merge only here).
+    probe pass — so their numeric buckets ride along unchanged; the
+    fallback capacity is ``fallback_need`` of both phases' observed
+    sub-products (the state keeps them separate so policy serialization
+    and ``note_admit`` call sites are unchanged; they merge only here).
     """
     if state.sym_max is None:
         return None
@@ -395,8 +402,7 @@ def trim_schedule(state: PolicyState, current, *, m: int,
     if not fused and state.num_max is not None:
         num = trim_buckets(state.num_max, num, m, headroom)
     active = bool(sym[-1]) or (not fused and bool(num[-1]))
-    fall_max = max(state.sym_fall_max,
-                   0 if fused else state.num_fall_max)
+    fall_max = fallback_need(state.sym_fall_max, state.num_fall_max, fused)
     fall = trim_fallback(fall_max, current.fall_prod_bucket, headroom, active)
     if (sym == tuple(current.sym_row_buckets)
             and num == tuple(current.num_row_buckets)

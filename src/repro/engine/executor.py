@@ -214,7 +214,7 @@ def _execute_steps(A: CSR, B: CSR, plan: SpgemmPlan,
             sched.num_row_buckets if sched else None,
             sched.fall_prod_bucket if sched else 0)
         # Both phases share ONE fallback expansion capacity (one arena
-        # bucket per plan): each phase runs with the shared max.
+        # bucket per plan): each eager phase runs with the shared max.
         fall = max(sym_fall, num_fall)
         C, _, _ = spgemm_hash.numeric_scheduled(
             A, B, rpt, num_binning, num_ladder,
@@ -222,7 +222,13 @@ def _execute_steps(A: CSR, B: CSR, plan: SpgemmPlan,
             fallback_prod_capacity=fall,
             single_access=config.hash_single_access,
             interpret=config.interpret)
-        hash_sched = HashSchedule(sym_buckets, num_buckets, fall)
+        # The plan learns what its steady executable needs, which for a
+        # fused plan is less (webbase-1M's numeric fallback rung expands
+        # 16x more than its symbolic one).
+        hash_sched = HashSchedule(sym_buckets, num_buckets,
+                                  autotune.fallback_need(
+                                      sym_fall, num_fall,
+                                      config.fuse_numeric))
     elif config.fuse_esc:
         C = esc.spgemm_fused(A, B, prod_capacity=prod_capacity,
                              nnz_capacity=nnz_capacity)
@@ -632,6 +638,11 @@ class SpgemmEngine:
             "opsparse_epilogue_gathered_slots_total")
         self._epilogue_entries = reg.counter(
             "opsparse_epilogue_entries_total")
+        # The hash products and the part of them on the ESC fallback
+        # rung (see _note_products).
+        self._hash_products = reg.counter("opsparse_hash_products_total")
+        self._fallback_products = reg.counter(
+            "opsparse_fallback_products_total")
         # Arena gauges/counters: snapshot-set from the (possibly shared)
         # arena's own accounting on every lease transition, so multiple
         # engines publishing into their own registries agree.
@@ -785,7 +796,8 @@ class SpgemmEngine:
             # Same bucket math as host_schedule/trim_schedule (the ONE
             # shared copy in spgemm_hash), fed estimated counts: exact
             # rows per sym rung, band-high rows per num rung, and the
-            # band-high fallback products shared by both phases.
+            # band-high fallback products of both phases, merged by
+            # autotune.fallback_need.
             m_cap = next_bucket(plan.a_sig.nrows,
                                 minimum=spgemm_hash._ROW_BUCKET_MIN)
             packs = (plan.sym_ladder.rows_per_block
@@ -800,7 +812,9 @@ class SpgemmEngine:
                 spgemm_hash.schedule_bucket(c, m_cap=m_cap,
                                             headroom=state.headroom)
                 for c in est.num_counts)
-            fall = max(est.sym_fall_prod, est.num_fall_prod)
+            fall = autotune.fallback_need(est.sym_fall_prod,
+                                          est.num_fall_prod,
+                                          config.fuse_numeric)
             fall_bucket = (spgemm_hash.fallback_capacity_bucket(
                 fall, headroom=state.headroom) if fall else 0)
             sched = HashSchedule(sym_buckets, num_buckets, fall_bucket)
@@ -1383,6 +1397,7 @@ class SpgemmEngine:
                                            schedule_overflow=not schedule_ok)
             self._note_hash_admit(rec, fetched[2], fetched[3])
             self._note_epilogue(plan, total_nnz - int(fetched[4]))
+            self._note_products(total_nprod, int(fetched[3]))
         elif plan.config.method == "hash":
             (C, tnp, tnz, sym_binning, num_binning,
              sym_fall, num_fall, fall_nnz) = handles
@@ -1405,6 +1420,7 @@ class SpgemmEngine:
             self._note_hash_admit(rec, fetched[2], fetched[4],
                                   num_sizes=fetched[3], num_fall=fetched[5])
             self._note_epilogue(plan, total_nnz - int(fetched[6]))
+            self._note_products(total_nprod, int(fetched[4]))
         else:
             C, tnp, tnz, sym_binning, num_binning = handles
             with self.telemetry.span("verify_sync", uid=rec.uid):
@@ -1577,6 +1593,13 @@ class SpgemmEngine:
             ladder, buckets, nnz_capacity=plan.nnz_bucket,
             row_packing=packing))
         self._epilogue_entries.inc(entries)
+
+    def _note_products(self, total_nprod: int, fallback_prod: int) -> None:
+        """Count one admitted hot hash product's intermediate products,
+        and those of them in rows that the n_prod binning puts on the ESC
+        fallback rung, from the totals the verify sync fetched."""
+        self._hash_products.inc(total_nprod)
+        self._fallback_products.inc(fallback_prod)
 
     def _grow_and_redo(self, rec: _Pending, total_nprod: int,
                        total_nnz: int, *,

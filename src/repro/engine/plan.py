@@ -77,7 +77,7 @@ class HashSchedule:
 
     sym_row_buckets: Tuple[int, ...]
     num_row_buckets: Tuple[int, ...]
-    fall_prod_bucket: int   # one shared sym/num fallback expansion capacity
+    fall_prod_bucket: int   # fallback expansion capacity (autotune.fallback_need)
 
     def union(self, other: "HashSchedule") -> "HashSchedule":
         """Elementwise max — schedules only ever grow (progressive

@@ -72,7 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import phases
 from repro.core import esc
-from repro.core.analysis import exclusive_sum_in_place
+from repro.core.analysis import exclusive_sum_in_place, nprod_per_entry
 from repro.core.binning import Binning
 from repro.core.binning_ranges import BinLadder
 from repro.core.csr import CSR, gather_rows
@@ -297,6 +297,12 @@ def _make_kernel(t_size: int, stride: int, rps: int, rows_cap: int, *,
     return kernel
 
 
+def kernel_name(t_size: int) -> str:
+    """The name of a hash kernel with ``t_size``-slot tables, as its
+    compiled instruction and a device trace's op carry it."""
+    return f"opsparse_hash_t{t_size}"
+
+
 def _hash_call(rows, count, operands, *, t_size: int, rows_cap: int,
                pack: int, emit_nnz: bool, single_access: bool,
                interpret: Optional[bool]):
@@ -343,8 +349,11 @@ def _hash_call(rows, count, operands, *, t_size: int, rows_cap: int,
     kernel = _make_kernel(t_size, stride, rps, rows_cap, values=values,
                           emit_nnz=emit_nnz, single_access=single_access,
                           val_dtype=val_dtype)
+    # The name becomes the compiled custom call's, so a device trace
+    # tells the rungs apart by their table size.
     outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(count, *srcs)
+                          interpret=interpret,
+                          name=kernel_name(t_size))(count, *srcs)
     tables = [t.reshape(steps * rps, stride)[:rows_cap]
               for t in outs[:2 if values else 0]]
     per_row = [c.reshape(-1)[:rows_cap] for c in outs[2 if values else 0:]]
@@ -711,17 +720,18 @@ def symbolic_binned(A: CSR, B: CSR, binning: Binning, ladder: BinLadder, *,
 
 
 def nprod_of_rows(A: CSR, B: CSR, rows: jax.Array) -> jax.Array:
-    b_sizes = B.nnz_per_row()
+    """n_prod of each of ``rows`` (ids past A's last row read its last
+    row; callers mask them): a running sum of A's products per entry,
+    differenced at the rows' pointers.  It holds one value per stored
+    entry of A whatever the number of rows; a mask of A's storage per
+    row held rows x storage (webbase-1M's numeric fallback rung: 4,096
+    rows x 4.19M slots, 16 GB, in the eager cold path).  The int32 sum
+    may wrap; a row's difference is exact while the row has under 2^31
+    products."""
+    csum = jnp.cumsum(nprod_per_entry(A, B))
+    csum = jnp.concatenate([jnp.zeros(1, csum.dtype), csum])
     safe_rows = jnp.minimum(rows, A.nrows - 1)
-    lo, hi = A.rpt[safe_rows], A.rpt[safe_rows + 1]
-
-    def per_row(l, h):
-        # Sum of B-row sizes over a variable slice — segment via mask.
-        idx = jnp.arange(A.capacity, dtype=jnp.int32)
-        mask = (idx >= l) & (idx < h)
-        return jnp.sum(jnp.where(mask, b_sizes[jnp.minimum(A.col, B.nrows - 1)], 0))
-
-    return jax.vmap(per_row)(lo, hi)
+    return csum[A.rpt[safe_rows + 1]] - csum[A.rpt[safe_rows]]
 
 
 def numeric_scheduled(A: CSR, B: CSR, rpt: jax.Array, binning: Binning,

@@ -57,6 +57,18 @@ def _csr(sharding):
 
 _SYM, _NUM = symbolic_ladder(1.2), numeric_ladder(2.0)
 
+# Row buckets of rungs 4-7 in webbase-1M's fused schedule at the engine's
+# 2x headroom (3,662 / 1,880 / 555 / 328 rows;
+# chipbench/configs/webbase-1M.json).
+WEB_UPPER_BUCKETS = {4: 8192, 5: 4096, 6: 2048, 7: 1024}
+
+
+def _custom_call_names(hlo):
+    """The instruction names of the compiled Pallas kernels, without the
+    ``.<n>`` XLA appends."""
+    return [re.sub(r"\.\d+$", "", name) for name in re.findall(
+        r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
+
 
 @pytest.mark.parametrize("kernel,t_size,pack,rows_cap", [
     # smallest (packed) rung at the full row bucket, and the top rung
@@ -66,6 +78,10 @@ _SYM, _NUM = symbolic_ladder(1.2), numeric_ladder(2.0)
     ("symbolic", _SYM.table_sizes[-1], 1, 8),
     ("numeric", _NUM.table_sizes[0], 1, M_CAP),
     ("numeric", _NUM.table_sizes[-1], 1, 8),
+] + [
+    # rungs 4-7 (one row per grid step) at webbase-1M's row buckets
+    ("fused", _SYM.table_sizes[b], 1, rows_cap)
+    for b, rows_cap in WEB_UPPER_BUCKETS.items()
 ])
 def test_hash_kernel_compiles_for_v5e(one_chip, kernel, t_size, pack,
                                       rows_cap):
@@ -85,7 +101,32 @@ def test_hash_kernel_compiles_for_v5e(one_chip, kernel, t_size, pack,
 
     compiled = jax.jit(call).lower(rows, count, A.rpt, A.col,
                                    A.val).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _custom_call_names(compiled.as_text()) == [
+        spgemm_hash.kernel_name(t_size)]
+
+
+def test_fused_executable_of_every_rung_compiles_for_v5e(one_chip):
+    """The steady fused hash executable with all eight table rungs and
+    the ESC fallback rung populated, as webbase-1M's schedule has them,
+    compiles for the chip; each rung's custom call carries its table
+    size in its instruction name, which a device trace shows as the op's
+    name (what ``upper_rung_kernel_s`` reads)."""
+    plan = _tiny_plan("hash")
+    plan = plan.with_hash_schedule(HashSchedule(
+        (32, 32) + (8,) * 6 + (16,), plan.hash_schedule.num_row_buckets,
+        1 << 12))
+    sig = plan.a_sig
+    spec = plan.workspace_spec()
+    A = CSR(rpt=_arr(one_chip, sig.nrows + 1),
+            col=_arr(one_chip, sig.cap_bucket),
+            val=_arr(one_chip, sig.cap_bucket, jnp.float32),
+            shape=(sig.nrows, sig.ncols))
+    hlo = _build_fused_hash_executable(plan).lower(
+        A, A, _arr(one_chip, spec.i32_cells),
+        _arr(one_chip, spec.val_cells, jnp.float32)).compile().as_text()
+    assert sorted(_custom_call_names(hlo)) == sorted(
+        spgemm_hash.kernel_name(t) for t in _SYM.table_sizes)
+    assert "opsparse.fallback" in hlo
 
 
 def test_esc_hot_executable_compiles_for_v5e(one_chip):

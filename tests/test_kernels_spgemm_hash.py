@@ -248,3 +248,19 @@ def test_numeric_epilogue_sorted_and_complete():
     for i in range(m):
         seg = coln[rptn[i]:rptn[i + 1]]
         assert (np.diff(seg) > 0).all()
+
+
+def test_nprod_of_rows_matches_the_row_sums():
+    """n_prod of chosen rows (repeats and the last row among them; an
+    id past A's last row reads that row) equals the sum of B's row sizes
+    over each row's entries, on padded storage."""
+    A, B = _pair(11, 40, 30, 30, 3.0, 4.0, dist="powerlaw")
+    A = A.with_capacity(next_bucket(A.capacity + 100))
+    rpt, col = np.asarray(A.rpt), np.asarray(A.col)
+    b_sizes = np.diff(np.asarray(B.rpt))
+    want = np.array([b_sizes[col[rpt[r]:rpt[r + 1]]].sum()
+                     for r in range(40)])
+    rows = jnp.asarray([0, 39, 7, 7, 40, 3] + list(range(40)), jnp.int32)
+    got = np.asarray(jax.jit(spgemm_hash.nprod_of_rows)(A, B, rows))
+    np.testing.assert_array_equal(
+        got, want[np.minimum(np.asarray(rows), 39)])
